@@ -33,8 +33,9 @@ def pair_signals(y: Array, x: Array, lookback):
 
     ``lookback`` may be traced (vmap over lookback grids).
     """
-    alpha, beta = rolling.rolling_ols(y, x, lookback, fill=0.0)
-    spread = y - (alpha + beta * x)
+    beta, resid = rolling.rolling_ols_residual(y, x, lookback, fill=0.0)
+    # Warmup spread is y - (0 + 0 x) = y.
+    spread = jnp.where(rolling.valid_mask(y.shape[-1], lookback), resid, y)
     z = rolling.rolling_zscore(spread, lookback, fill=0.0)
     # The spread itself needs `lookback` bars of OLS warmup, and its z-score
     # another `lookback`; mask both.

@@ -393,8 +393,8 @@ class DecisionPlane:
         # it (observe_completion), the shadow score reads it.
         self._spu: dict[str, list] = {}
         self._spu_global = [0, _DEFAULT_SPU]
-        # wid -> set of strategy families completed there (compile-cache
-        # hit proxy: first sighting pays the cold wall).
+        # wid -> set of strategy families completed there (stands in for a
+        # compile-cache hit: the first sighting pays the cold wall).
         self._fams: dict[str, set] = {}
         # jid -> (wid, family, model units) parked at scoring time so a
         # later completion can calibrate spu without re-deriving units.

@@ -68,8 +68,8 @@ log = logging.getLogger("dbx.flight")
 #: bounded — the obs-cardinality lint sanctions this call the same way
 #: it sanctions ``tenant_bucket``.
 _KINDS = ("job_fail", "slo_breach", "straggler", "requeue_expired",
-          "lockdep", "residual", "collect_fail", "admin", "signal",
-          "regret")
+          "lockdep", "residual", "collect_fail", "scenario_fused_fail",
+          "admin", "signal", "regret")
 
 #: Lock-free trigger inbox for hostile acquire-site contexts. The
 #: lockdep violation hook fires while the offending locks are still

@@ -14,6 +14,15 @@ Design notes (TPU-first):
   shifted read uses a clipped ``take`` so that ``w`` may be a *traced* scalar —
   this is what lets a parameter sweep ``vmap`` over thousands of window lengths
   without recompilation or dynamic shapes.
+- **Compensated prefix sums** (:func:`prefix_sum2`): a plain f32 prefix sum
+  carries a rounding error that grows with the history, and the difference
+  ``cs[t] - cs[t-w]`` inherits it whole — on 1,260 daily bars ~1% of SMA
+  crossover and ~1–4% of Bollinger / pairs Sharpe cells differed from a
+  float64 run, and the answer hung on how the compiler associated the scan
+  (the fused kernels and this module disagreed on the chip wherever their
+  shapes differed). The prefix is carried as an unevaluated pair
+  ``hi + lo``; each windowed sum is ``(hi[t] - hi[t-w]) + (lo[t] - lo[t-w])``,
+  accurate to a few ulps of the window sum whatever the scan order.
 - **Numerical stability in f32**: variance via ``E[x^2] - E[x]^2`` on raw
   price levels (~1e2) catastrophically cancels in float32. All second-moment
   ops first subtract the per-series mean (a constant shift changes neither
@@ -54,13 +63,112 @@ def valid_mask(T: int, window) -> Array:
     return jnp.arange(T) >= window - 1
 
 
+def prefix_sum2(x: Array) -> tuple[Array, Array]:
+    """Compensated inclusive prefix sum along the last axis: ``(hi, lo)``
+    with ``hi + lo`` the exact prefix sum up to the (tiny) rounding of
+    ``lo``'s own scan.
+
+    ``hi`` is the plain ``cumsum`` in whatever order the backend scans.
+    Each step's rounding ``prev + x[t] - hi[t]`` is recovered exactly: the
+    two-sum of ``hi[t-1] + x[t]`` gives ``s`` and its error, and ``s -
+    hi[t]`` is exact (Sterbenz) because both round the same sum. ``lo`` is
+    the prefix sum of those errors, so the pair telescopes to the exact sum
+    independently of the association of either scan. Every windowed sum
+    in this repo — generic, fused, paged, time-sharded — differences these
+    pairs (:func:`window_diff`)."""
+    hi = jnp.cumsum(x, axis=-1)
+    prev = jnp.concatenate([jnp.zeros_like(hi[..., :1]), hi[..., :-1]],
+                           axis=-1)
+    s, err = two_sum(prev, x)
+    return hi, jnp.cumsum((s - hi) + err, axis=-1)
+
+
+def two_sum(a, b):
+    """``(s, err)`` with ``s = fl(a + b)`` and ``a + b == s + err`` exactly
+    (Knuth's branch-free two-sum)."""
+    s = a + b
+    v = s - a
+    return s, (a - (s - v)) + (b - v)
+
+
+def window_diff(hi, lo, hi_lag, lo_lag):
+    """Windowed sum from a :func:`prefix_sum2` pair and its lagged read:
+    the ``hi`` difference is exact or rounds at the window sum's own scale,
+    and ``lo`` carries what the ``hi`` scan dropped."""
+    return (hi - hi_lag) + (lo - lo_lag)
+
+
+# ---------------------------------------------------------------------------
+# Double-f32 window moments for the rolling OLS
+#
+# The hedge ratio's covariance is ``sxy - sx sy / w`` on legs centred by
+# their FULL-history means; on price series a 10–50-bar window's mean sits
+# many local standard deviations from that centre, so the subtraction
+# cancels ~2 orders of magnitude and a last-bit change anywhere upstream
+# (a different reduction order for the centre, a fused multiply-add) moved
+# beta by up to ~1e-3 and flipped pairs entries between two compilations
+# of one formula. These helpers carry each window moment as an unevaluated
+# f32 pair (``s + e``) built from exact products (:func:`two_prod`) and
+# compensated prefix sums, and form ``cov`` / ``var`` in that precision:
+# the cancellation then costs nothing, and beta is the same to a few ulps
+# whichever path or compiler computed it.
+# ---------------------------------------------------------------------------
+
+def two_prod(a, b):
+    """``(p, err)`` with ``p = fl(a * b)`` and ``a * b == p + err`` exactly
+    (Dekker's split product; needs no fused multiply-add)."""
+    p = a * b
+    ah, al = _split(a)
+    bh, bl = _split(b)
+    return p, ((ah * bh - p) + ah * bl + al * bh) + al * bl
+
+
+def _split(a):
+    nbits = jnp.finfo(a.dtype).nmant + 1
+    c = jnp.asarray(2.0 ** ((nbits + 1) // 2) + 1.0, a.dtype) * a
+    ah = c - (c - a)
+    return ah, a - ah
+
+
+def prefix_prod2(a, b) -> tuple[Array, Array]:
+    """:func:`prefix_sum2` of the exact products ``a * b``: the pair's
+    ``lo`` also carries each product's rounding error."""
+    p, err = two_prod(a, b)
+    hi, lo = prefix_sum2(p)
+    return hi, lo + jnp.cumsum(err, axis=-1)
+
+
+def window_sum2(hi, lo, hi_lag, lo_lag):
+    """:func:`window_diff` kept as an unevaluated pair ``(s, e)``."""
+    s, e = two_sum(hi, -hi_lag)
+    return s, e + (lo - lo_lag)
+
+
+def ols_slope2(sx, sy, sxx, sxy, w, eps):
+    """OLS slope ``cov / (var + eps)`` from :func:`window_sum2` pairs of the
+    centred legs' window moments, with ``cov = sxy - sx sy / w`` and ``var
+    = sxx - sx^2 / w`` formed in double-f32."""
+    def centred(s2, a, b):
+        p, pe = two_prod(a[0], b[0])
+        pe = pe + (a[0] * b[1] + a[1] * b[0])
+        q = p / w                          # (p + pe) / w as q + qe
+        r, re = two_prod(q, w)
+        qe = ((p - r) - re + pe) / w
+        d, e = two_sum(s2[0], -q)
+        return d + ((e - qe) + s2[1])
+    cov = centred(sxy, sx, sy)
+    var = jnp.maximum(centred(sxx, sx, sx), 0.0)
+    return cov / (var + eps)
+
+
 def rolling_sum(x: Array, window, *, fill=jnp.nan) -> Array:
     """Rolling sum over the trailing ``window`` bars (inclusive), same length.
 
     ``out[..., t] = sum(x[..., t-window+1 : t+1])``; warmup -> ``fill``.
     """
-    cs = jnp.cumsum(x, axis=-1)
-    out = cs - _shifted(cs, window)
+    hi, lo = prefix_sum2(x)
+    lag = _shifted(jnp.stack([hi, lo]), window)
+    out = window_diff(hi, lo, lag[0], lag[1])
     return _mask_warmup(out, window, fill)
 
 
@@ -117,22 +225,43 @@ def rolling_ols(y: Array, x: Array, window, *, eps=1e-12, fill=jnp.nan):
     linear-regression kernel behind the pairs-trade config
     (``BASELINE.json`` configs[3]).
     """
+    w, mx, my, _, _, sx, sy, beta = _ols_moments(y, x, window, eps)
+    # Means of the *uncentered* series: mean_w(x) = sx/w + mx.
+    alpha = (sy / w + my) - beta * (sx / w + mx)
+    return _mask_warmup(alpha, window, fill), _mask_warmup(beta, window, fill)
+
+
+def rolling_ols_residual(y: Array, x: Array, window, *, eps=1e-12,
+                         fill=jnp.nan):
+    """``(beta, resid)``: :func:`rolling_ols`'s hedge ratio and the residual
+    ``y - (alpha + beta x)``, evaluated as ``(yc - mean_w(yc)) - beta (xc -
+    mean_w(xc))`` on the series-centred legs — the same value, without
+    subtracting ``alpha + beta x`` at price level (~1e2), where beta's
+    last-bit rounding alone moved the residual by ~1e-3 of its scale and
+    flipped pairs entries between two compilations of one formula."""
+    w, _, _, xc, yc, sx, sy, beta = _ols_moments(y, x, window, eps)
+    resid = (yc - sy / w) - beta * (xc - sx / w)
+    return _mask_warmup(beta, window, fill), _mask_warmup(resid, window, fill)
+
+
+def _ols_moments(y: Array, x: Array, window, eps):
+    """Windowed moments of the series-centred legs and the OLS slope
+    (:func:`ols_slope2`): ``(w, mx, my, xc, yc, sx, sy, beta)``, ``sx`` /
+    ``sy`` as plain window sums."""
     w = jnp.asarray(window, y.dtype)
     mx = jnp.mean(x, axis=-1, keepdims=True)
     my = jnp.mean(y, axis=-1, keepdims=True)
     xc, yc = x - mx, y - my
 
-    sx = rolling_sum(xc, window, fill=jnp.nan)
-    sy = rolling_sum(yc, window, fill=jnp.nan)
-    sxx = rolling_sum(xc * xc, window, fill=jnp.nan)
-    sxy = rolling_sum(xc * yc, window, fill=jnp.nan)
-
-    cov = sxy - sx * sy / w
-    var = jnp.maximum(sxx - sx * sx / w, 0.0)
-    beta = cov / (var + eps)
-    # Means of the *uncentered* series: mean_w(x) = sx/w + mx.
-    alpha = (sy / w + my) - beta * (sx / w + mx)
-    return _mask_warmup(alpha, window, fill), _mask_warmup(beta, window, fill)
+    pairs = [prefix_sum2(xc), prefix_sum2(yc), prefix_prod2(xc, xc),
+             prefix_prod2(xc, yc)]
+    stacked = jnp.stack([a for pair in pairs for a in pair])
+    lag = _shifted(stacked, window)
+    sx, sy, sxx, sxy = (window_sum2(stacked[2 * i], stacked[2 * i + 1],
+                                    lag[2 * i], lag[2 * i + 1])
+                        for i in range(4))
+    beta = ols_slope2(sx, sy, sxx, sxy, w, eps)
+    return w, mx, my, xc, yc, sx[0] + sx[1], sy[0] + sy[1], beta
 
 
 def ema(x: Array, *, span=None, alpha=None, fill=None) -> Array:

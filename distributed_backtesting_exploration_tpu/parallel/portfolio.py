@@ -37,7 +37,6 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 
-from ._shardmap_compat import shard_map
 from ..models.base import Strategy
 from ..ops import metrics as metrics_mod
 from ..ops import pnl as pnl_mod
@@ -210,7 +209,7 @@ def sharded_portfolio_returns(mesh, close, positions, *, weights=None,
         exposure = jax.lax.psum(part_exp, ax)
         return net, 1.0 + jnp.cumsum(net, axis=-1), exposure
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ax, None), P(ax, None), P(ax)),
         out_specs=(P(), P(), P()),

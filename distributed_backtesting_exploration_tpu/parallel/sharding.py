@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._shardmap_compat import shard_map
 from ..ops import metrics as metrics_mod
 from ..parallel import sweep as sweep_mod
 
@@ -116,7 +115,7 @@ def sharded_sweep(mesh: Mesh, ohlcv, strategy, grid, *, cost=0.0,
             ohlcv_blk, strategy, grid_rep, cost=cost, bar_mask=mask_blk,
             periods_per_year=periods_per_year)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(type(ohlcv)(*(row for _ in ohlcv)),
                   {k: rep for k in grid}, mask_spec),
@@ -162,7 +161,7 @@ def best_over_grid(mesh: Mesh, ohlcv, strategy, grid, *, metric: str = "sharpe",
         param = flat_idx % vals.shape[1]
         return best_v, ticker.astype(jnp.int32), param.astype(jnp.int32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(type(ohlcv)(*(row for _ in ohlcv)),
                   {k: rep for k in grid}, mask_spec),

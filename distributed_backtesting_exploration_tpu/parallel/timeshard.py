@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._shardmap_compat import axis_size, shard_map
+from ..ops import rolling
 
 TIME_AXIS = "time"
 
@@ -91,7 +91,7 @@ def sharded_cumsum(mesh: Mesh, x, *, axis_name: str = TIME_AXIS):
         offset = _exclusive_block_offset(cs[..., -1], axis_name)
         return cs + offset[..., None]
 
-    return shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
                          check_vma=False)(x)
 
 
@@ -112,7 +112,7 @@ def sharded_linear_scan(mesh: Mesh, a, b, *, axis_name: str = TIME_AXIS):
     def local_simple(a_blk, b_blk):
         return _linear_scan_local(a_blk, b_blk, axis_name)
 
-    return shard_map(local_simple, mesh=mesh, in_specs=(spec, spec),
+    return jax.shard_map(local_simple, mesh=mesh, in_specs=(spec, spec),
                          out_specs=spec, check_vma=False)(a, b)
 
 
@@ -129,7 +129,7 @@ def _linear_scan_local(a_blk, b_blk, axis_name: str):
         combine, (a_blk, b_blk), axis=-1)
     A = prefix_a[..., -1]
     B = y_local[..., -1]
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     all_A = jax.lax.all_gather(A, axis_name)   # (n, ...)
     all_B = jax.lax.all_gather(B, axis_name)
@@ -178,7 +178,7 @@ def sharded_ema(mesh: Mesh, x, *, span=None, alpha=None,
         gidx = jnp.arange(Tb) + jax.lax.axis_index(axis_name) * Tb
         return _ema_local(x_blk, gidx, alpha, axis_name)
 
-    return shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
                          check_vma=False)(x)
 
 
@@ -210,7 +210,7 @@ def chunked_scan(step, init_carry, inputs, *, chunk: int, unroll: int = 8):
 
 def _from_left(x_blk, k: int, axis_name: str):
     """Last ``k`` elements of the LEFT neighbor's block (zeros on chip 0)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, i + 1) for i in range(n - 1)]
     return jax.lax.ppermute(x_blk[..., -k:], axis_name, perm)
 
@@ -286,24 +286,55 @@ def _block_returns(close_blk, gidx, axis_name: str):
                      close_blk / jnp.where(gidx == 0, 1.0, prev_close) - 1.0)
 
 
-def _cumsum_ext(series_blk, halo_w: int, axis_name: str):
-    """Global prefix sum of a time-sharded series, plus a ``halo_w``-bar
-    left halo — the lagged-read window every cumsum-difference rolling sum
-    needs. Returns ``(cs, cs_ext)``."""
-    cs = jnp.cumsum(series_blk, axis=-1)
-    cs = cs + _exclusive_block_offset(cs[..., -1], axis_name)[..., None]
+def _cumsum_ext(series_blk, halo_w: int, axis_name: str, prefix=None):
+    """Global compensated prefix sum (``rolling.prefix_sum2``, or the
+    local ``(hi, lo)`` pair ``prefix`` gives) of a time-sharded series,
+    plus a ``halo_w``-bar left halo — the lagged-read window every
+    cumsum-difference rolling sum needs. Returns ``(cs, cs_ext)``, each
+    stacking the ``(hi, lo)`` pair on a new leading axis.
+
+    The chips to the left contribute their block totals as pairs, summed
+    with ``two_sum`` in chip order, and the offset joins each local pair
+    the same way, so the global pair is as exact as the one-device
+    ``rolling.rolling_sum``'s and the two agree whatever the block
+    boundaries."""
+    hi, lo = rolling.prefix_sum2(series_blk) if prefix is None else prefix
+    idx = jax.lax.axis_index(axis_name)
+    totals = jax.lax.all_gather(jnp.stack([hi[..., -1], lo[..., -1]]),
+                                axis_name)                  # (n, 2, ...)
+    off_hi = jnp.zeros_like(hi[..., -1])
+    off_lo = jnp.zeros_like(off_hi)
+    for j in range(totals.shape[0]):
+        left = j < idx
+        off_hi, err = rolling.two_sum(off_hi,
+                                      jnp.where(left, totals[j, 0], 0.0))
+        off_lo = off_lo + jnp.where(left, totals[j, 1], 0.0) + err
+    hi, err = rolling.two_sum(hi, off_hi[..., None])
+    cs = jnp.stack([hi, lo + off_lo[..., None] + err])
     return cs, jnp.concatenate(
         [_from_left(cs, halo_w, axis_name), cs], axis=-1)
 
 
 def _windowed_sum_blk(cs, cs_ext, gidx, w: int, halo_w: int):
-    """Trailing-``w`` rolling sum from the extended prefix sum:
-    ``cs[t] - cs[t-w]`` with a zero lagged read in the global warmup
-    (``t < w``) — ``rolling.rolling_sum``'s semantics, blockwise."""
+    """Trailing-``w`` rolling sum from the extended prefix pair:
+    ``rolling.window_diff`` of ``cs[t]`` and ``cs[t-w]`` with a zero
+    lagged read in the global warmup (``t < w``) —
+    ``rolling.rolling_sum``'s semantics, blockwise."""
+    lagged = _lagged_blk(cs, cs_ext, gidx, w, halo_w)
+    return rolling.window_diff(cs[0], cs[1], lagged[0], lagged[1])
+
+
+def _windowed_sum2_blk(cs, cs_ext, gidx, w: int, halo_w: int):
+    """:func:`_windowed_sum_blk` as an unevaluated ``rolling.window_sum2``
+    pair (the rolling OLS's double-f32 moments)."""
+    lagged = _lagged_blk(cs, cs_ext, gidx, w, halo_w)
+    return rolling.window_sum2(cs[0], cs[1], lagged[0], lagged[1])
+
+
+def _lagged_blk(cs, cs_ext, gidx, w: int, halo_w: int):
     Tb = cs.shape[-1]
-    lagged = jax.lax.slice_in_dim(
-        cs_ext, halo_w - w, halo_w - w + Tb, axis=-1)
-    return cs - jnp.where(gidx >= w, lagged, 0.0)
+    return jnp.where(gidx >= w, jax.lax.slice_in_dim(
+        cs_ext, halo_w - w, halo_w - w + Tb, axis=-1), 0.0)
 
 
 def _windowed_zscore_local(series_blk, gidx, window: int, halo_w: int,
@@ -356,7 +387,7 @@ def _transition_positions_local(maps, axis_name: str):
     # load-sensitive native compile (signals.prefix_compose_maps).
     pm, p0, pp = signals.prefix_compose_maps(maps)
 
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     # One latency-bound collective, not three: the block summary is a
     # stacked (3, ...) map — (next state from -1, from 0, from +1).
@@ -424,7 +455,7 @@ def sharded_band_positions(mesh: Mesh, z, valid, z_entry, z_exit=0.0, *,
         return _band_positions_local(z_blk, valid_blk, z_entry, z_exit,
                                      axis_name)
 
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
                          out_specs=spec, check_vma=False)(
         z, jnp.broadcast_to(valid, z.shape))
 
@@ -489,7 +520,7 @@ def sharded_sma_backtest(mesh: Mesh, close, fast: int, slow: int, *,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -550,7 +581,7 @@ def sharded_bollinger_backtest(mesh: Mesh, close, window: int, k: float, *,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -619,7 +650,7 @@ def sharded_rsi_backtest(mesh: Mesh, close, period: int, band: float, *,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -692,19 +723,24 @@ def sharded_pairs_backtest(mesh: Mesh, y_close, x_close, lookback: int,
         # All four OLS moment sums through ONE stacked _cumsum_ext
         # (collectives are latency-bound; one all_gather + one ppermute
         # serve the stack — same discipline as _windowed_zscore_local).
-        cs, cs_ext = _cumsum_ext(jnp.stack([xc, yc, xc * xc, xc * yc]),
-                                 halo_w, axis_name)
-        s = _windowed_sum_blk(cs, cs_ext, gidx, lookback, halo_w)
-        sx, sy, sxx, sxy = s[0], s[1], s[2], s[3]
-        cov = sxy - sx * sy / w_f
-        var = jnp.maximum(sxx - sx * sx / w_f, 0.0)
-        beta = cov / (var + eps)
-        alpha = (sy / w_f + my) - beta * (sx / w_f + mx)
+        # rolling.rolling_ols's double-f32 moments: exact products, and
+        # window sums kept as pairs into rolling.ols_slope2.
+        pfx = [rolling.prefix_sum2(xc), rolling.prefix_sum2(yc),
+               rolling.prefix_prod2(xc, xc), rolling.prefix_prod2(xc, yc)]
+        cs, cs_ext = _cumsum_ext(
+            None, halo_w, axis_name,
+            prefix=tuple(jnp.stack([p[k] for p in pfx]) for k in (0, 1)))
+        s, e = _windowed_sum2_blk(cs, cs_ext, gidx, lookback, halo_w)
+        sx, sy, sxx, sxy = ((s[i], e[i]) for i in range(4))
+        beta = rolling.ols_slope2(sx, sy, sxx, sxy, w_f, eps)
+        sx, sy = sx[0] + sx[1], sy[0] + sy[1]
         ok_w = gidx >= lookback - 1
-        beta = jnp.where(ok_w, beta, 0.0)
+        # The residual on the centred legs (rolling.rolling_ols_residual).
         # Warmup spread is exactly y (rolling_ols fill=0.0): those bars
         # feed the z-score's series mean and early windowed sums.
-        spread = jnp.where(ok_w, y_blk - (alpha + beta * x_blk), y_blk)
+        spread = jnp.where(ok_w, (yc - sy / w_f) - beta * (xc - sx / w_f),
+                           y_blk)
+        beta = jnp.where(ok_w, beta, 0.0)
 
         z = _windowed_zscore_local(spread, gidx, lookback, halo_w, T,
                                    axis_name, eps=eps)
@@ -727,7 +763,7 @@ def sharded_pairs_backtest(mesh: Mesh, y_close, x_close, lookback: int,
                                   axis_name=axis_name, prev_pos=prev_pos)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
                          out_specs=out_specs, check_vma=False)(
         y_close, x_close)
 
@@ -856,7 +892,7 @@ def sharded_donchian_backtest(mesh: Mesh, close, window: int, *,
             periods_per_year=periods_per_year, axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -888,7 +924,7 @@ def sharded_donchian_hl_backtest(mesh: Mesh, close, high, low, window: int,
             periods_per_year=periods_per_year, axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=out_specs, check_vma=False)(
         close, high, low)
 
@@ -960,7 +996,7 @@ def sharded_stochastic_backtest(mesh: Mesh, close, high, low, window: int,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=out_specs, check_vma=False)(
         close, high, low)
 
@@ -1024,7 +1060,7 @@ def sharded_trix_backtest(mesh: Mesh, close, span: int, signal: int, *,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -1075,7 +1111,7 @@ def sharded_momentum_backtest(mesh: Mesh, close, lookback: int, *,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -1124,7 +1160,7 @@ def sharded_bollinger_touch_backtest(mesh: Mesh, close, window: int,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -1192,7 +1228,7 @@ def sharded_keltner_backtest(mesh: Mesh, close, high, low, window: int,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=out_specs, check_vma=False)(
         close, high, low)
 
@@ -1253,7 +1289,7 @@ def sharded_vwap_backtest(mesh: Mesh, close, volume, window: int, k: float,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
                          out_specs=out_specs, check_vma=False)(close, volume)
 
 
@@ -1321,7 +1357,7 @@ def sharded_macd_backtest(mesh: Mesh, close, fast: int, slow: int,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=spec,
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
                          out_specs=out_specs, check_vma=False)(close)
 
 
@@ -1401,5 +1437,5 @@ def sharded_obv_backtest(mesh: Mesh, close, volume, window: int, *,
                                   axis_name=axis_name)
 
     out_specs = Metrics(*(rep for _ in Metrics._fields))
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
                          out_specs=out_specs, check_vma=False)(close, volume)

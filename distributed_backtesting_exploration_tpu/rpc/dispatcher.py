@@ -3182,14 +3182,12 @@ def main(argv=None) -> None:
     from ..analysis import lockdep
 
     lockdep.maybe_install()
-    if os.environ.get("DBX_COMPILE_CACHE_DIR"):
-        # Operator opted the dispatcher host into the persistent compile
-        # cache (a dispatcher that also runs local jax work — bench, a
-        # colocated worker). Best-effort; gated on the env knob because
-        # importing jax is heavyweight for a pure control-plane process.
-        from .. import tune as tune_mod
+    # The dispatcher's own jax work (scenario materialization) compiles
+    # into the one resolved cache dir (tune.compile_cache). Importing jax
+    # does not touch a device.
+    from .. import tune as tune_mod
 
-        tune_mod.configure()
+    tune_mod.configure()
     dispatcher = build_dispatcher(args)
     queue = dispatcher.queue
     server = DispatcherServer(dispatcher, bind=args.bind,

@@ -31,6 +31,7 @@ import argparse
 import logging
 import os
 import queue as queue_mod
+import sys
 import threading
 import time
 import uuid
@@ -230,6 +231,7 @@ class Worker:
         self._connected = True  # edge-triggered logging, reference CONNECTED
         self.jobs_completed = 0
         self.completions_dropped = 0
+        self.batches_failed = 0   # backend exceptions (_backend_failed)
         self._compute_thread: threading.Thread | None = None
         # Failed completion RPCs park here with a due time instead of
         # sleep-retrying on the control thread (advisor finding: inline
@@ -278,6 +280,9 @@ class Worker:
         self._c_dropped = self.obs.counter(
             "dbx_worker_completions_dropped_total",
             help="completions dropped after retry exhaustion")
+        self._c_batch_failures = self.obs.counter(
+            "dbx_worker_batch_failures_total",
+            help="batches whose backend submit/collect raised")
         # Every per-worker-labeled metric (the jobs/sec gauge and the
         # collector-maintained channel/deferred/busy gauges) is created in
         # run() and removed in its finally — a constructed-but-never-run
@@ -366,10 +371,7 @@ class Worker:
                     for completion in self.backend.process(batch):
                         self._out.put(completion)
             except Exception as e:
-                log.exception("backend failed on a %d-job batch; jobs will "
-                              "be re-queued by lease expiry", len(batch))
-                obs_flight.trigger("collect_fail", subject=self.worker_id,
-                                   jobs=len(batch), reason=repr(e))
+                self._backend_failed(batch, e)
             finally:
                 self._pipeline_batch_end()
 
@@ -488,10 +490,7 @@ class Worker:
                              worker=self.worker_id):
                 return (self.backend.submit(batch), batch)
         except Exception as e:
-            log.exception("backend failed submitting a %d-job batch; jobs "
-                          "will be re-queued by lease expiry", len(batch))
-            obs_flight.trigger("collect_fail", subject=self.worker_id,
-                               jobs=len(batch), reason=repr(e))
+            self._backend_failed(batch, e)
             return None
 
     def _collect_into_out(self, pending) -> None:
@@ -503,10 +502,20 @@ class Worker:
                 for completion in self.backend.collect(handle):
                     self._out.put(completion)
         except Exception as e:
-            log.exception("backend failed on a %d-job batch; jobs will "
-                          "be re-queued by lease expiry", len(batch))
-            obs_flight.trigger("collect_fail", subject=self.worker_id,
-                               jobs=len(batch), reason=repr(e))
+            self._backend_failed(batch, e)
+
+    def _backend_failed(self, batch, e: Exception) -> None:
+        """The one handler for a backend exception on any compute path:
+        log it, fire the flight trigger and count it. The jobs go back to
+        the queue by lease expiry; the count makes the failure loud —
+        ``main`` exits non-zero and ``chip_smoke.py`` fails on it."""
+        log.exception("backend failed on a %d-job batch; jobs will be "
+                      "re-queued by lease expiry", len(batch))
+        with self._pipeline_lock:
+            self.batches_failed += 1
+        self._c_batch_failures.inc()
+        obs_flight.trigger("collect_fail", subject=self.worker_id,
+                           jobs=len(batch), reason=repr(e))
 
     # -- control side ------------------------------------------------------
 
@@ -1130,6 +1139,11 @@ def main(argv=None) -> None:
     finally:
         if metrics_srv is not None:
             metrics_srv.stop()
+    if worker.batches_failed:
+        # A backend that raised is never a clean exit: its jobs only went
+        # back to the queue by lease expiry.
+        sys.exit(f"worker {worker.worker_id}: {worker.batches_failed} "
+                 f"batch(es) failed in the backend")
 
 
 if __name__ == "__main__":

@@ -2,11 +2,10 @@
 
 Two cooperating layers:
 
-1. **Local persistent cache** — :func:`configure` points JAX's persistent
-   compilation cache at a directory and applies the best-effort threshold
-   options (names have drifted across jax generations — kept in ONE place;
-   tests/conftest.py imports this instead of carrying its own copy, and
-   dispatcher/worker mains call it at startup). Every cache entry is keyed
+1. **Local persistent cache** — :func:`configure` turns on JAX's
+   persistent compilation cache at the one directory
+   :func:`default_cache_dir` resolves (tests/conftest.py, bench.py,
+   chip_smoke.py and the dispatcher/worker mains all call it). Every cache entry is keyed
    by jax's own HLO/config hash, so re-runs of unchanged kernels skip
    straight to execution.
 
@@ -32,11 +31,14 @@ degradation ladder never fails a job.
 from __future__ import annotations
 
 import hashlib
+import logging
 import os
 import tempfile
 import threading
 
 from .. import obs
+
+log = logging.getLogger("dbx.tune")
 
 _DEFAULT_STORE_MB = 256
 # Entries larger than this never cross the wire (a single pathological
@@ -50,52 +52,48 @@ def compile_store_max_bytes() -> int:
                                     _DEFAULT_STORE_MB)) * 1024 * 1024)
 
 
+# The package's parent: the checkout root (`<checkout>/.jax_cache` is
+# listed in .gitignore).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
 def default_cache_dir() -> str:
-    """The runtime cache directory: ``DBX_COMPILE_CACHE_DIR`` or a stable
-    per-user tempdir path (stable so restarts re-hit their own entries)."""
-    d = os.environ.get("DBX_COMPILE_CACHE_DIR")
-    if d:
-        return d
-    return os.path.join(tempfile.gettempdir(), "dbx_jax_cache")
+    """THE persistent compile cache directory: ``JAX_COMPILATION_CACHE_DIR``
+    when the environment sets it, else ``<checkout>/.jax_cache`` — a fixed
+    path, so a restarted worker finds its own entries."""
+    return os.environ.get(_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
 
 
-def configure(path: str | None = None, *,
-              min_compile_time_s: float = 0.5,
+def configure(*, min_compile_time_s: float = 0.5,
               min_entry_bytes: int = 0) -> str | None:
-    """Point JAX's persistent compilation cache at ``path`` (default
-    :func:`default_cache_dir`). THE one implementation of the threshold
-    best-effort (conftest + dispatcher + worker all route here). Returns
-    the configured path, or None when jax itself is unusable — callers
-    degrade to uncached compiles, never fail."""
-    path = path or default_cache_dir()
+    """Turn on JAX's persistent compilation cache at
+    :func:`default_cache_dir` — the one resolver worker, dispatcher,
+    bench, the test harness and ``chip_smoke.py`` share. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and no
+    directory is set in code. Returns the directory, or None when jax
+    refuses the options — callers then compile uncached, never fail.
+
+    jax's XLA sub-caches stay off: jax places them inside the cache
+    directory and folds their paths into every entry's key, so two
+    workers whose directories differ (checkouts at different paths)
+    would never hit each other's entries, fleet-exchanged or not."""
+    path = default_cache_dir()
     try:
         import jax
-    except Exception:   # pragma: no cover - jax is baked into the image
-        return None
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+
+        if not os.environ.get(_ENV):
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_compile_time_s)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          min_entry_bytes)
     except Exception:
+        log.exception("persistent compile cache left off")
         return None
-    # Threshold configs are best-effort — option names have drifted
-    # across jax generations (the reason this lives in ONE module).
-    for opt, val in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             min_compile_time_s),
-            ("jax_persistent_cache_min_entry_size_bytes",
-             min_entry_bytes)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:  # pragma: no cover - older/newer jax
-            pass
-    # A mid-process dir switch (bench's second-worker A/B) must drop the
-    # old backend-held cache handle; best-effort across jax generations.
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:
-        pass
     return path
 
 

@@ -12,7 +12,7 @@ import pytest
 from distributed_backtesting_exploration_tpu.models.base import get_strategy
 from distributed_backtesting_exploration_tpu.ops import fused
 from distributed_backtesting_exploration_tpu.parallel import sweep
-from distributed_backtesting_exploration_tpu.utils import data
+from distributed_backtesting_exploration_tpu.utils import data, verify
 
 
 def _check(n_tickers, T, fast_axis, slow_axis, cost=1e-3, seed=0):
@@ -940,3 +940,58 @@ def test_lanes_cap_change_recompiles_in_process(monkeypatch):
     # same setting again: cache hit, no further compile
     fused.fused_sma_sweep(close, fast, slow)
     assert fused._fused_call._cache_size() == n_before + 1
+
+
+@pytest.mark.parametrize("family", ["pairs", "bollinger"])
+def test_ragged_short_row_matches_its_standalone_run(family):
+    """A short row in a ragged group centres its moments on its OWN real
+    bars, not on the group's padded width: its Sharpe row equals the same
+    ticker swept alone. (The pad used to shift the centering constant,
+    and the rounding of every centred moment moved with it — on v5e about
+    half of a ragged pairs group's cells flipped against the reference.)"""
+    d = data.synthetic_ohlcv(4, 1260, seed=3)
+    close = np.asarray(d.close)
+    y, x = close[:2].copy(), close[2:].copy()
+    y[1, 1100:], x[1, 1100:] = y[1, 1099], x[1, 1099]
+    lens = np.asarray([1260, 1100], np.int32)
+    if family == "pairs":
+        g = sweep.product_grid(
+            lookback=np.arange(10, 50, 2, dtype=np.float32),
+            z_entry=np.asarray([0.5, 1.0, 1.5, 2.0, 2.5, 3.0], np.float32))
+
+        def run(a, b, t_real=None):
+            return fused.fused_pairs_sweep(
+                a, b, np.asarray(g["lookback"]), np.asarray(g["z_entry"]),
+                t_real=t_real, cost=1e-3).sharpe
+        ragged = run(y, x, lens)
+        alone = run(y[1:, :1100], x[1:, :1100])
+    else:
+        g = sweep.product_grid(
+            window=np.arange(10, 50, 2, dtype=np.float32),
+            k=np.asarray([0.5, 1.0, 1.5, 2.0, 2.5, 3.0], np.float32))
+
+        def run(a, t_real=None):
+            return fused.fused_bollinger_sweep(
+                a, np.asarray(g["window"]), np.asarray(g["k"]),
+                t_real=t_real, cost=1e-3).sharpe
+        ragged = run(y, lens)
+        alone = run(y[1:, :1100])
+    # Rounding still differs (a masked sum is not jnp.mean's reduction
+    # tree); what must not differ is any position path: no cell moves by
+    # the verify budget's flip threshold.
+    np.testing.assert_allclose(np.asarray(ragged)[1:], np.asarray(alone),
+                               rtol=verify.FLIP_REL, atol=1e-4)
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False),
+                                           ("gpu", None)])
+def test_interpret_default_follows_the_platform(monkeypatch, platform,
+                                                want):
+    """Interpret mode only on the CPU, compiled on the TPU, and an error
+    anywhere else — never a silent interpret-mode run off the chip."""
+    monkeypatch.setattr(fused.jax, "default_backend", lambda: platform)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            fused.interpret_default()
+    else:
+        assert fused.interpret_default() is want

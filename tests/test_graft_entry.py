@@ -1,10 +1,9 @@
 """Driver-contract tests for ``__graft_entry__``.
 
-Round 1's only red check was ``dryrun_multichip`` asserting on the ambient
-device count instead of provisioning its own mesh (MULTICHIP_r01: rc=1 in the
-1-TPU driver process).  These tests pin the fix: the inline path on the
-conftest's 8 virtual devices, and the subprocess re-exec path that a
-device-starved process (like the driver's) must take.
+``dryrun_multichip`` once asserted on the ambient device count instead of
+provisioning its own mesh, and failed in a one-device process. These tests
+pin the fix: the inline path on the conftest's 8 virtual devices, and the
+CPU re-exec path that a device-starved process takes.
 """
 
 import os

@@ -367,7 +367,7 @@ def test_kernel_hygiene_flags_float64_leak():
 
     mod = _load_bad_kernels()
     x = np.ones((4, 8), np.float32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings = jaxpr_rules.check_traced(
             "f64", mod.kernel_with_f64, [x], path="bad_kernel.py", line=22)
     assert any("float64" in f.message for f in findings)

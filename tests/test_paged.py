@@ -402,6 +402,54 @@ def test_paged_kill_switch_and_knob_validation(monkeypatch):
     assert fused.resolve_page_bars() == 64
 
 
+@pytest.mark.parametrize("strategy", ["bollinger", "bollinger_touch",
+                                      "vwap_reversion"])
+def test_band_families_never_batch_ragged(monkeypatch, strategy):
+    # ROADMAP B7: a ragged group changes a band family's rolling-variance
+    # rounding (on the v5e, up to 5 % of Sharpe cells), so these families
+    # group by exact length: each job's result is bit-identical to the
+    # same job run alone, whatever it was batched with.
+    be = _backend(monkeypatch)
+    series = [data.OHLCV(*(np.asarray(f)[:t]
+                           for f in _series(64, 70 + i)))
+              for i, t in enumerate((64, 41, 64))]
+    specs = _specs(series, BOLL_GRID, strategy=strategy)
+    assert [be._length_bucket(s, BOLL_GRID) for s in specs] == [
+        -len(s.ohlcv) for s in specs]
+    batched = {c.job_id: c.metrics for c in be.process(specs)}
+    for spec in specs:
+        (alone,) = _backend(monkeypatch).process([spec])
+        assert alone.metrics == batched[spec.id], spec.id
+    pairs = pb.JobSpec(strategy="pairs", panel_digest="d" * 32,
+                       panel_bytes_len=1000)
+    assert be._length_bucket(pairs, {}) == -1000
+
+
+def test_groups_split_to_the_device_memory_budget(monkeypatch):
+    # A group the size of a worker's lease is cut into launches that each
+    # fit the per-chip HBM budget; the cut changes no result.
+    be = _backend(monkeypatch)
+    specs = _specs([_series(64, 80 + i) for i in range(5)], SMA_GRID)
+    combos = np.prod([len(v) for v in
+                      wire.grid_from_proto(specs[0].grid).values()])
+    per_job = (128 * be._HBM_BYTES_PER_BAR
+               + combos * be._HBM_BYTES_PER_COMBO)
+    assert be._group_limit(specs) == be._HBM_BUDGET // per_job
+    monkeypatch.setattr(be, "_HBM_BUDGET", 2 * per_job)
+    assert be._group_limit(specs) == 2
+    finish, launched = be._finish_group, []
+
+    def counting_finish(group, *a, **k):
+        launched.append(len(group))
+        return finish(group, *a, **k)
+
+    monkeypatch.setattr(be, "_finish_group", counting_finish)
+    split = {c.job_id: c.metrics for c in be.process(specs)}
+    assert launched == [2, 2, 1]
+    whole = {c.job_id: c.metrics for c in _backend(monkeypatch).process(specs)}
+    assert split == whole
+
+
 def test_paged_fields_match_fused_registry():
     # ONE source of truth: the worker prepares page tables from
     # fused.paged_fields, and the two registries' field tuples AND grid

@@ -99,3 +99,81 @@ def test_rolling_sum_under_jit():
     np.testing.assert_allclose(
         np.asarray(f(x, 7)),
         np.asarray(rolling.rolling_sum(x, 7, fill=0.0)), rtol=1e-6)
+
+
+# --- compensated window sums and the double-f32 OLS (PR 21) ---------------
+
+LONG = (100.0 * np.exp(np.cumsum(RNG.normal(0, 0.02, 8192)))).astype(
+    np.float32)
+
+
+@pytest.mark.parametrize("w", [5, 50, 200])
+def test_rolling_sum_is_exact_to_the_window_on_long_histories(w):
+    # A plain f32 cumsum difference carries the whole prefix's rounding
+    # (~1e-4 of the window sum at 8,192 price-level bars); the compensated
+    # pair keeps each window sum within a few ulps of itself.
+    got = np.asarray(rolling.rolling_sum(jnp.asarray(LONG), w))
+    x = LONG.astype(np.float64)
+    cs = np.cumsum(x)
+    want = cs - np.concatenate([np.zeros(w), cs[:-w]])
+    np.testing.assert_allclose(got[w - 1:], want[w - 1:], rtol=2e-6)
+
+
+def test_prefix_sum2_telescopes_whatever_the_scan_order(monkeypatch):
+    # The pair's sum is the exact prefix sum even when the backend's scan
+    # associates differently: emulate one with a blocked cumsum.
+    x = jnp.asarray(LONG)
+    hi, lo = rolling.prefix_sum2(x)
+    plain = jnp.cumsum
+
+    def blocked(a, axis=-1):
+        b = a.reshape(a.shape[:-1] + (-1, 128))
+        loc = plain(b, axis=-1)
+        off = plain(loc[..., -1], axis=-1) - loc[..., -1]
+        return (loc + off[..., None]).reshape(a.shape)
+
+    monkeypatch.setattr(jnp, "cumsum", blocked)
+    hi_b, lo_b = rolling.prefix_sum2(x)
+    exact = np.cumsum(LONG.astype(np.float64))
+    for h, l in ((hi, lo), (hi_b, lo_b)):
+        total = np.asarray(h, np.float64) + np.asarray(l, np.float64)
+        np.testing.assert_allclose(total, exact, rtol=1e-9)
+    assert not np.array_equal(np.asarray(hi), np.asarray(hi_b))
+
+
+def test_two_prod_is_exact():
+    a = RNG.normal(0, 50, 1000).astype(np.float32)
+    b = RNG.normal(0, 50, 1000).astype(np.float32)
+    p, err = rolling.two_prod(jnp.asarray(a), jnp.asarray(b))
+    np.testing.assert_array_equal(
+        np.asarray(p, np.float64) + np.asarray(err, np.float64),
+        a.astype(np.float64) * b.astype(np.float64))
+
+
+@pytest.mark.parametrize("w", [10, 32])
+def test_rolling_ols_residual_matches_float64_and_jit(w):
+    # beta is formed in double-f32 on the centred legs, so it no longer
+    # hangs on how one compilation rounded the cancellation: f32 agrees
+    # with float64, and the jitted and eager runs agree with each other.
+    y32, x32 = (jnp.asarray(LONG[:1260]),
+                jnp.asarray(LONG[2000:3260] * 0.7 + 3.0))
+    beta, resid = rolling.rolling_ols_residual(y32, x32, w)
+    jbeta, jresid = jax.jit(rolling.rolling_ols_residual,
+                            static_argnums=2)(y32, x32, w)
+    y, x = np.asarray(y32, np.float64), np.asarray(x32, np.float64)
+    want_b = np.full(1260, np.nan)
+    want_r = np.full(1260, np.nan)
+    for t in range(w - 1, 1260):
+        xs, ys = x[t - w + 1: t + 1], y[t - w + 1: t + 1]
+        b, a = np.polyfit(xs, ys, 1)
+        want_b[t], want_r[t] = b, y[t] - (a + b * x[t])
+    scale = np.nanstd(want_r)
+    np.testing.assert_allclose(np.asarray(beta)[w - 1:], want_b[w - 1:],
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(resid)[w - 1:], want_r[w - 1:],
+                               atol=1e-4 * scale)
+    # (single-f32 moments put them up to 7.6e-4 apart on the smoke's pairs)
+    np.testing.assert_allclose(np.asarray(jbeta), np.asarray(beta),
+                               rtol=1e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(jresid), np.asarray(resid),
+                               atol=1e-5 * scale)

@@ -744,6 +744,54 @@ def test_fleet_compile_cache_round_trip_over_grpc(tmp_path):
         srv.stop()
 
 
+def test_fleet_entry_hits_in_another_checkout_and_cache_dir(tmp_path):
+    """A kernel compiled by a worker whose checkout and cache dir sit at
+    one path, its entry carried by CacheSync into another worker's dir,
+    is a jax persistent-cache hit there for the same kernel traced from
+    a source file at another path. It was a miss while jax's XLA
+    sub-caches were on: they fold the cache dir into every key."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from distributed_backtesting_exploration_tpu.tune import compile_cache
+
+    src = "def kern(x):\n    return jnp.cumsum(jnp.sin(x) * 3.0 + x) - 1\n"
+    options = ("jax_compilation_cache_dir",
+               "jax_persistent_cache_enable_xla_caches",
+               "jax_persistent_cache_min_compile_time_secs",
+               "jax_persistent_cache_min_entry_size_bytes")
+    prior = {o: getattr(jax.config, o) for o in options}
+    events = []
+
+    def listener(event, **kw):
+        events.append(event)
+
+    def hits_compiling_in(checkout, cache_dir):
+        ns = {"jnp": jnp}
+        exec(compile(src, str(checkout / "kern.py"), "exec"), ns)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        compilation_cache.reset_cache()
+        events.clear()
+        jax.jit(ns["kern"])(np.arange(8, dtype=np.float32))
+        return events.count("/jax/compilation_cache/cache_hits")
+
+    jax.monitoring.register_event_listener(listener)
+    try:
+        compile_cache.configure(min_compile_time_s=0.0)
+        dirs = [str(tmp_path / c / ".jax_cache") for c in "ab"]
+        sync_a, sync_b = (tune.CacheSync(d, runtime_tag="t|cpu")
+                          for d in dirs)
+        assert hits_compiling_in(tmp_path / "a", dirs[0]) == 0
+        assert sync_b.install(sync_a.poll_new()) == 1
+        assert hits_compiling_in(tmp_path / "b", dirs[1]) == 1
+    finally:
+        jax.monitoring.unregister_event_listener(listener)
+        for option, value in prior.items():
+            jax.config.update(option, value)
+        compilation_cache.reset_cache()
+
+
 def test_stats_reply_ships_fleet_schedule():
     import grpc
 
@@ -762,3 +810,47 @@ def test_stats_reply_ships_fleet_schedule():
         channel.close()
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# The one compile-cache directory resolver
+# ---------------------------------------------------------------------------
+
+def _record_config_updates(monkeypatch):
+    import jax
+
+    names = []
+    real = jax.config.update
+
+    def update(name, value):
+        names.append(name)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", update)
+    return names
+
+
+def test_compile_cache_env_dir_wins_and_nothing_else_is_set(monkeypatch,
+                                                            tmp_path):
+    from distributed_backtesting_exploration_tpu.tune import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    names = _record_config_updates(monkeypatch)
+    assert compile_cache.default_cache_dir() == str(tmp_path)
+    assert compile_cache.configure() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in names
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+
+    from distributed_backtesting_exploration_tpu.tune import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    names = _record_config_updates(monkeypatch)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert compile_cache.default_cache_dir() == want
+    assert compile_cache.configure() == want
+    assert "jax_compilation_cache_dir" in names
+    assert jax.config.jax_compilation_cache_dir == want

@@ -780,17 +780,13 @@ def test_flight_keys_present(flight_bench):
 
 def test_autotune_keys_present(autotune_bench):
     """The substrate-autotuner A/B's acceptance numbers
-    (autotuned_vs_default_speedup{family} with its modeled twin, and the
-    fleet compile-cache second_worker_compile_wall_{cold,warm}_s /
-    compile_wall_reduction pair) ride these BENCH JSON keys — a renamed
+    (autotuned_vs_default_speedup{family} with its modeled twin) ride
+    these BENCH JSON keys — a renamed
     key would silently invalidate the next round's measurement."""
     at = autotune_bench["roofline"]["autotune"]
     for key in ("autotuned_vs_default_speedup",
                 "autotuned_vs_default_speedup_modeled", "families",
-                "speedup_families_ok", "second_worker_compile_wall_cold_s",
-                "second_worker_compile_wall_warm_s",
-                "compile_wall_reduction", "fleet_entries_offered",
-                "fleet_entries_installed", "platform"):
+                "speedup_families_ok", "platform"):
         assert key in at, key
     # >= 3 kernel families measured, each with a winner recorded.
     assert len(at["autotuned_vs_default_speedup"]) >= 3
@@ -799,9 +795,6 @@ def test_autotune_keys_present(autotune_bench):
         assert row["tuned_s_per_sweep"] > 0.0, fam
         assert at["autotuned_vs_default_speedup"][fam] > 0.0, fam
         assert at["autotuned_vs_default_speedup_modeled"][fam] > 0.0, fam
-    assert at["second_worker_compile_wall_cold_s"] > 0.0
-    assert at["second_worker_compile_wall_warm_s"] > 0.0
-    assert at["compile_wall_reduction"] > 0.0
     assert autotune_bench["configs"]["autotune"] > 0.0
 
 
