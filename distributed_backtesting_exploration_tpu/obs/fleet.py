@@ -11,8 +11,9 @@ on the dispatcher, no extra coordinator):
   compact telemetry frame to ``JobsRequest.telemetry_json`` — monotone
   counters, per-stage cost EWMAs + fixed-bucket histograms (fed by a
   span listener over the existing ``worker.decode`` /
-  ``worker.compile`` / ``worker.execute`` / ``worker.d2h``
-  instrumentation), cache residency summaries (counts + byte totals + a
+  ``worker.compile`` / ``worker.execute`` / ``worker.device_wait`` /
+  ``worker.d2h`` / ``worker.pack`` instrumentation), cache residency
+  summaries (counts + byte totals + a
   small top-K digest sketch, never full key lists), pipeline depth and
   backend capability flags. A frame rides only when something changed
   or the heartbeat interval elapsed (``DBX_FLEET_HEARTBEAT_S``) — the
@@ -120,7 +121,9 @@ _SPAN_TO_STAGE = {
     "worker.compile": "compile",
     "worker.execute": "execute",
     "worker.append": "execute",
+    "worker.device_wait": "execute",
     "worker.d2h": "d2h",
+    "worker.pack": "d2h",
 }
 
 #: Shared fixed bucket bounds: worker-side accumulation and the

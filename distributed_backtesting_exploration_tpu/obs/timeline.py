@@ -67,7 +67,14 @@ SPAN_STAGE = {
     "worker.decode": ("decode", 2),
     "worker.compile": ("compile", 2),
     "worker.execute": ("execute", 2),
+    # The collect lane, per group: the device finishing the group is
+    # execute; the copy of the ready result and the packing of one result
+    # block per job are d2h. The compute thread's waits (worker.wait_batch
+    # for work, worker.wait_slot for a pipeline slot) carry no trace and
+    # no stage: they say why the device idled, not where a job's wall went.
+    "worker.device_wait": ("execute", 2),
     "worker.d2h": ("d2h", 2),
+    "worker.pack": ("d2h", 2),
     "worker.report": ("report", 2),
     # The digest-miss recovery RPC (can fire inside the decode window on
     # the compute-thread race leg): network wall, charged to transport —
@@ -115,7 +122,8 @@ _LANE_SPANS = {
     "worker.prefetch": "submit", "worker.decode": "submit",
     "worker.compile": "submit", "worker.execute": "submit",
     "worker.append": "submit", "worker.submit": "submit",
-    "worker.d2h": "collect", "worker.collect": "collect",
+    "worker.device_wait": "collect", "worker.d2h": "collect",
+    "worker.pack": "collect", "worker.collect": "collect",
 }
 # worker.process (the serial loop's whole-batch envelope) joins NEITHER
 # lane: it covers both halves of its batch, so counting it as submit

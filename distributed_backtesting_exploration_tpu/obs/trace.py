@@ -25,6 +25,15 @@ in-memory ring (:func:`recent_spans`) exported via ``/stats.json`` and
 GetStats ``obs_json`` so a live process can be asked "what just ran"
 without any log file.
 
+Device trace: while a ``jax.profiler`` trace is active, every
+:func:`span` (and every :func:`timer` given a ``name``) is also a host
+event of that name in the trace, on the profiler's clock beside the
+device ops — so an idle stretch of the device reads against the program
+phase the host was in. This module never imports jax: it annotates only
+once jax is loaded in the process, so a process without jax (a
+dispatcher alone) never annotates. With no trace active the cost is one
+``is_enabled`` check per span.
+
 ``timed`` (log-only), ``StepTimer`` (running throughput meter) and
 ``device_profile`` (jax.profiler wrapper) move here from ``utils.trace``,
 which remains as a deprecation shim for one release.
@@ -39,6 +48,7 @@ import itertools
 import logging
 import os
 import random
+import sys
 import threading
 import time
 
@@ -113,6 +123,30 @@ def job_trace_pairs(jobs) -> list:
         if tid:
             out.append((tid, getattr(j, "parent_span_id", "")))
     return out
+
+
+# The profiler's host-annotation class (``jax.profiler.TraceAnnotation``),
+# resolved at the first span after jax is loaded; None until then.
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An open profiler annotation named ``name`` when a trace is active,
+    else None. Never imports jax: while ``jax.profiler`` is not loaded in
+    the process there is nothing to annotate."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                      None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
 
 
 # ---------------------------------------------------------------------------
@@ -275,7 +309,9 @@ def emit_span(name: str, t0_wall: float, dur_s: float, *,
     The synthesized-span entry point for phases that are not ``with``
     blocks — the dispatcher's queue-wait (enqueue ts -> dispatch ts) and
     the job's end-to-end wall (enqueue ts -> completion recorded) exist
-    only as two timestamps, never as one open stack frame. ``pairs``
+    only as two timestamps, never as one open stack frame. Such a span
+    never reaches an active profiler trace: the trace takes host events
+    only as they happen, not after the fact. ``pairs``
     (a list of ``(trace_id, parent_span_id)``) overrides ``trace_id`` for
     the multi-job-batch case. An enclosing local span on this thread, if
     any, becomes the local parent — the compute backend emits its
@@ -300,8 +336,12 @@ def span(name: str, **attrs):
     Durations land in ``dbx_span_seconds{span=name}``; the completed span
     (with its ``trace_id``/``span_id``/``parent_id`` triple, ``t0`` wall
     start, and ``dur_s``) goes to the in-memory ring and — when the JSONL
-    event log is configured — to the log. Exceptions propagate; the span
+    event log is configured — to the log, and is a host event of the
+    same name in an active profiler trace. Exceptions propagate; the span
     records either way (``ok`` marks it).
+
+    The block receives the attrs dict: an attr known only inside the
+    block (the size of the batch a wait returned) is set on it there.
     """
     stack = getattr(_tls, "stack", None)
     if stack is None:
@@ -310,32 +350,40 @@ def span(name: str, **attrs):
     sid = new_span_id()
     stack.append((name, sid))
     pairs = _trace_ctx.get()
+    ann = _annotation(name)
     t0_wall = time.time()
     t0 = time.perf_counter()
     ok = True
     try:
-        yield
+        yield attrs
     except BaseException:
         ok = False
         raise
     finally:
         dur = time.perf_counter() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
         stack.pop()
         _record_span(name, t0_wall, dur, span_id=sid, stack_parent=parent,
                      pairs=pairs, ok=ok, **attrs)
 
 
 @contextlib.contextmanager
-def timer(hist):
+def timer(hist, name: str | None = None):
     """Observe the block's wall into a pre-resolved histogram — in a
     ``finally``, so failures and timeouts are measured too (an RPC
     latency histogram that excludes the 30 s deadline-exceeded calls
-    reads healthy while throughput is zero)."""
+    reads healthy while throughput is zero). ``name`` also makes the
+    block a host event of that name in an active profiler trace, and
+    nothing more: no span record, no second histogram."""
+    ann = _annotation(name) if name else None
     t0 = time.perf_counter()
     try:
         yield
     finally:
         hist.observe(time.perf_counter() - t0)
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 @contextlib.contextmanager
@@ -375,12 +423,6 @@ class StepTimer:
     def __init__(self, gauge=None):
         self.t0 = time.monotonic()
         self.units = 0.0
-        self._gauge = gauge
-
-    def bind_gauge(self, gauge) -> None:
-        """Attach (or detach, with None) the published-rate gauge after
-        construction — for owners whose metric lifecycle starts later
-        than their own (e.g. a Worker binds in run(), not __init__)."""
         self._gauge = gauge
 
     def add(self, n: float) -> None:

@@ -846,6 +846,7 @@ def _fused_call(close, onehot_d, warm, t_real, *, windows: tuple,
             (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="sma_crossover_kernel",
         compiler_params=_COMPILER_PARAMS,
     )(returns3, table_arg, onehot_d, warm,
       *_tr_args(t_real, T_real))
@@ -1231,7 +1232,7 @@ def _cumsum_window_tools(windows: tuple, T_pad: int):
 
 def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
                          t_real, *, T_pad: int, W_pad: int, P_real: int,
-                         T_real: int | None, interpret: bool,
+                         T_real: int | None, interpret: bool, name: str,
                          lanes_cap: int = 256, aux_rows=(),
                          scratch_shapes=(), lanes_env: int = 0):
     """Shared launch for every band-machine strategy (Bollinger, RSI, VWAP):
@@ -1246,7 +1247,8 @@ def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
     ``z_table=None`` selects the in-kernel substrate: ``aux_rows`` (each
     ``(N, T_pad)`` or ``(N, k, T_pad)``, delivered as ``(1, 1, T_pad)`` /
     ``(1, k, T_pad)`` lane-major blocks) and ``scratch_shapes`` carry the
-    VMEM-scratch z-table build instead (`_band_kernel_inline`)."""
+    VMEM-scratch z-table build instead (`_band_kernel_inline`). ``name``
+    is the kernel's name in the device trace, one per strategy."""
     N = close_p.shape[0]
     P_pad = k_lanes.shape[1]
     lanes = _widest_lanes(P_pad, lanes_cap, T_pad, lanes_env)
@@ -1284,6 +1286,7 @@ def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
         scratch_shapes=list(scratch_shapes),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=name,
     )(_rets3(close_p), *table_args, *aux_rows, onehot_w, k_lanes, warm,
       *_tr_args(t_real, T_real))
     return Metrics(*(
@@ -1335,6 +1338,8 @@ def _fused_boll_call(close, onehot_w, k_lanes, warm, t_real, *, windows: tuple,
             kernel, close_p, None, onehot_w, k_lanes, warm, t_real,
             T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
             interpret=interpret, lanes_cap=lanes_cap,
+            name=("bollinger_touch_kernel" if machine == "touch"
+                  else "bollinger_kernel"),
             aux_rows=[close_p, _prefix_rows(close_p), _prefix_rows(xc),
                       _prefix_rows(xc * xc)],
             scratch_shapes=[pltpu.VMEM((W_pad, T_pad), jnp.float32)],
@@ -1355,7 +1360,9 @@ def _fused_boll_call(close, onehot_w, k_lanes, warm, t_real, *, windows: tuple,
     return _band_machine_pallas(
         kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-        interpret=interpret, lanes_cap=lanes_cap, lanes_env=lanes_env)
+        interpret=interpret, lanes_cap=lanes_cap, lanes_env=lanes_env,
+        name=("bollinger_touch_kernel" if machine == "touch"
+              else "bollinger_kernel"))
 
 
 def _bollinger_family_sweep(close, window, k, *, machine: str, z_exit: float,
@@ -1681,6 +1688,7 @@ def _fused_pairs_call(y_close, x_close, onehot_w, k_lanes, zx_lanes, warm,
         out_shape=jax.ShapeDtypeStruct(
             (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
         interpret=interpret,
+        name="pairs_kernel",
         compiler_params=_COMPILER_PARAMS,
     )(zh_tbl, onehot_w, k_lanes, zx_lanes,
       warm, *_tr_args(t_real, T_real))
@@ -2012,7 +2020,7 @@ def _don_kernel_inline(r_ref, c_ref, crow_ref, hi_ref, lo_ref, ow_ref,
 
 def _single_window_pallas(kernel, close, tables, onehot_w, warm, t_real, *,
                           T_pad: int, W_pad: int, P_real: int,
-                          T_real: int | None, interpret: bool,
+                          T_real: int | None, interpret: bool, name: str,
                           aux_rows=(), scratch_shapes=(), lanes_cap=_LANES,
                           lanes_env: int = 0):
     """Shared pallas_call plumbing for the momentum/donchian kernels:
@@ -2024,7 +2032,8 @@ def _single_window_pallas(kernel, close, tables, onehot_w, warm, t_real, *,
     are forwarded to ``pallas_call`` — together they carry the in-kernel
     (VMEM-scratch) table builders, which take raw series rows instead of
     XLA-built ``(N, W_pad, T_pad)`` tables (see `_kernel_inline` for the
-    pattern and the scratch-persistence contract).
+    pattern and the scratch-persistence contract). ``name`` is the
+    kernel's name in the device trace, one per strategy.
     """
     N = close.shape[0]
     P_pad = onehot_w.shape[1]
@@ -2062,6 +2071,7 @@ def _single_window_pallas(kernel, close, tables, onehot_w, warm, t_real, *,
         scratch_shapes=list(scratch_shapes),
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
+        name=name,
     )(_rets3(close), close[..., None], *tables,
       *(row[:, None, :] for row in aux_rows), onehot_w, warm,
       *_tr_args(t_real, T_real))
@@ -2096,6 +2106,7 @@ def _fused_mom_call(close, onehot_l, warm, t_real, *, windows: tuple,
         return _single_window_pallas(
             kernel, close_p, [], onehot_l, warm, t_real, T_pad=T_pad,
             W_pad=W_pad, P_real=P_real, T_real=T_real, interpret=interpret,
+            name="momentum_kernel",
             aux_rows=[close_p],
             scratch_shapes=[pltpu.VMEM((W_pad, T_pad), jnp.float32)],
             lanes_cap=512, lanes_env=lanes_env)
@@ -2108,6 +2119,7 @@ def _fused_mom_call(close, onehot_l, warm, t_real, *, windows: tuple,
     return _single_window_pallas(
         kernel, close_p, [past_tbl], onehot_l, warm, t_real, T_pad=T_pad,
         W_pad=W_pad, P_real=P_real, T_real=T_real, interpret=interpret,
+        name="momentum_kernel",
         lanes_cap=512, lanes_env=lanes_env)
 
 
@@ -2178,6 +2190,7 @@ def _fused_don_call(close, hi_src, lo_src, onehot_w, warm, t_real, *,
             kernel, close_p, [], onehot_w, warm, t_real,
             T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
             interpret=interpret,
+            name="donchian_kernel",
             aux_rows=[close_p, _pad_last(hi_src, T_pad),
                       _pad_last(lo_src, T_pad)],
             scratch_shapes=[pltpu.VMEM((W_pad, T_pad), jnp.float32)],
@@ -2198,7 +2211,8 @@ def _fused_don_call(close, hi_src, lo_src, onehot_w, warm, t_real, *,
     return _single_window_pallas(
         kernel, close_p, [sig_tbl], onehot_w, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-        interpret=interpret, lanes_cap=256)
+        interpret=interpret, lanes_cap=256,
+        name="donchian_kernel")
 
 
 def _resolve_table(table: str | None, env_var: str, default: str,
@@ -2435,7 +2449,8 @@ def _fused_stoch_call(close, high, low, onehot_w, band_lanes, warm, t_real,
     return _band_machine_pallas(
         kernel, close_p, z_table, onehot_w, band_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-        interpret=interpret)
+        interpret=interpret,
+        name="stochastic_kernel")
 
 
 def fused_stochastic_sweep(close, high, low, window, band, *, t_real=None,
@@ -2525,7 +2540,8 @@ def _fused_keltner_call(close, high, low, onehot_w, k_lanes, warm, t_real,
     return _band_machine_pallas(
         kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-        interpret=interpret)
+        interpret=interpret,
+        name="keltner_kernel")
 
 
 def fused_keltner_sweep(close, high, low, window, k, *, t_real=None,
@@ -2628,7 +2644,8 @@ def _fused_rsi_call(close, onehot_p, band_lanes, warm, t_real, *,
     return _band_machine_pallas(
         kernel, close_p, z_tbl, onehot_p, band_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-        interpret=interpret)
+        interpret=interpret,
+        name="rsi_kernel")
 
 
 def fused_rsi_sweep(close, period, band, *, t_real=None, cost: float = 0.0,
@@ -2789,6 +2806,7 @@ def _fused_macd_call(close, onehot_d, a_sig, warm, t_real, *,
         out_shape=jax.ShapeDtypeStruct(
             (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
         interpret=interpret,
+        name="macd_kernel",
         compiler_params=_COMPILER_PARAMS,
     )(_rets3(close_p), ema_tbl, onehot_d, a_sig, warm,
       *_tr_args(t_real, T_real))
@@ -2985,6 +3003,7 @@ def _fused_obv_call(close, volume, onehot_w, warm, t_real, *,
             (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="obv_kernel",
         compiler_params=_COMPILER_PARAMS,
     )(_rets3(close_p), obv[:, :, None], table_arg, onehot_w, warm,
       *_tr_args(t_real, T_real))
@@ -3134,6 +3153,7 @@ def _fused_trix_call(close, onehot, a_sig, warm, t_real, *,
         out_shape=jax.ShapeDtypeStruct(
             (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
         interpret=interpret,
+        name="trix_kernel",
         compiler_params=_COMPILER_PARAMS,
     )(_rets3(close_p), e3_tbl, onehot, a_sig, warm,
       *_tr_args(t_real, T_real))
@@ -3255,7 +3275,8 @@ def _fused_vwap_call(close, volume, onehot_w, k_lanes, warm, t_real, *,
     return _band_machine_pallas(
         kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-        interpret=interpret)
+        interpret=interpret,
+        name="vwap_kernel")
 
 
 def fused_vwap_sweep(close, volume, window, k, *, t_real=None,

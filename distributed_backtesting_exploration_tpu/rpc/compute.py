@@ -421,17 +421,11 @@ class JaxSweepBackend:
         self._c_decode_bytes = reg.counter(
             "dbx_compute_decode_bytes_total",
             help="OHLCV payload bytes decoded")
-        self._h_collect = reg.histogram(
-            "dbx_compute_collect_seconds",
-            help="device drain + d2h wait per pending group")
         self._c_d2h_bytes = reg.counter(
             "dbx_compute_d2h_bytes_total",
             help="result bytes copied device->host")
         self._c_backtests = reg.counter(
             "dbx_backtests_total", help="(ticker x param) combos computed")
-        self._bt_rate = obs.StepTimer(reg.gauge(
-            "dbx_compute_backtests_per_sec",
-            help="combos/s since backend start"))
         self._h_jit = {
             phase: reg.histogram(
                 "dbx_jit_call_seconds",
@@ -2638,7 +2632,12 @@ class JaxSweepBackend:
                                   len(group), job0)
 
     def collect(self, pending) -> list[Completion]:
-        """Block for a submitted batch's results and pack completions."""
+        """Block for a submitted batch's results and pack completions.
+
+        Per group, three spans tile the work: ``worker.device_wait`` (the
+        device finishing the group), ``worker.d2h`` (the copy of the
+        ready result to the host) and ``worker.pack`` (one result block
+        per job)."""
         from ..ops.metrics import Metrics
 
         out: list[Completion] = []
@@ -2647,69 +2646,73 @@ class JaxSweepBackend:
             # _finish_group (the trailing h2d_hit flag).
             group, stacked, t0, n_real, extra = entry[:5]
             h2d_hit = bool(entry[5]) if len(entry) > 5 else False
-            t_wait = time.perf_counter()
-            if stacked is None:
-                host = None
-            else:
-                # The blocking device drain, traced per group: the d2h
-                # stage of each job's timeline (the worker.collect span
-                # above it covers the whole pending entry). cache_hit here
-                # reports that the SUBMIT-side panel upload was served
-                # from the device digest cache (no h2d for this group's
-                # panels); the drain itself is real work either way.
-                with obs.trace_context(obs.job_trace_pairs(group)), \
-                        obs.span("worker.d2h", jobs=len(group),
-                                 cache_hit=h2d_hit):
-                    host = np.asarray(stacked)
-            if host is not None:
-                # The blocking d2h drain: everything after here is host-side
-                # packing. Combo credit counts only real jobs (mesh pad rows
-                # are compute, not results) and is derived from each job's
-                # GRID, not the result shape — a top-k/best_returns group
-                # ships k (or 1) rows but computed the full grid, and the
-                # dispatcher's backtests_per_sec credits grid combos too
-                # (the two gauges must agree).
-                self._h_collect.observe(time.perf_counter() - t_wait)
-                self._c_d2h_bytes.inc(host.nbytes)
-                n_rows = min(host.shape[1], n_real)
-                combos = sum(wire.grid_n_combos(job.grid)
-                             for job in group[:n_rows])
-                self._c_backtests.inc(combos)
-                self._bt_rate.add(combos)
-            idx_host = ret_host = lens = None
+            # The group's device arrays: the stacked metrics, plus the
+            # top-k indices / best-returns rows of those modes.
+            idx_dev = ret_dev = lens = None
             mode = None
             if isinstance(extra, dict):          # best_returns (DBXP) group
                 mode = extra["kind"]
-                idx_host = np.asarray(extra["idx"])
-                ret_host = np.asarray(extra["returns"])
+                idx_dev, ret_dev = extra["idx"], extra["returns"]
                 lens = extra["lens"]
             elif extra is not None:              # top-k (DBXS) group
                 mode = "topk"
-                idx_host = np.asarray(extra[0])
-            elapsed = time.perf_counter() - t0
-            per_job = elapsed / max(len(group), 1)
-            # n_real (the jobs actually computed), NOT host.shape[1]: the
-            # mesh path pads rows to a chip multiple, and a pad row must
-            # never be reported as a validated-bad job's "result".
-            n_rows = 0 if host is None else min(host.shape[1], n_real)
-            for i, job in enumerate(group):
-                if i < n_rows:
-                    row = Metrics(*(host[k, i] for k in range(9)))
-                    if mode == "topk":
-                        blob = wire.topk_to_bytes(idx_host[i], row, extra[1])
-                    elif mode == "returns":
-                        # Trim to the job's real history: padded bars earn
-                        # exactly zero (repeat-last close + held position)
-                        # but belong to the group, not the job.
-                        blob = wire.best_returns_to_bytes(
-                            int(idx_host[i]), row,
-                            ret_host[i, :int(lens[i])], extra["metric"])
-                    else:
-                        blob = wire.metrics_to_bytes(row)
-                else:
-                    blob = b""   # validated-bad job: complete, no result
-                out.append(Completion(job.id, blob, per_job,
-                                      trace_id=job.trace_id))
+                idx_dev = extra[0]
+            host = idx_host = ret_host = None
+            with obs.trace_context(obs.job_trace_pairs(group)):
+                if stacked is not None:
+                    with obs.span("worker.device_wait", jobs=len(group)):
+                        self._jax.block_until_ready(
+                            (stacked, idx_dev, ret_dev))
+                    # cache_hit reports that the SUBMIT-side panel upload
+                    # was served from the device digest cache (no h2d for
+                    # this group's panels); the copy back is real work
+                    # either way.
+                    with obs.span("worker.d2h", jobs=len(group),
+                                  cache_hit=h2d_hit):
+                        host = np.asarray(stacked)
+                        if idx_dev is not None:
+                            idx_host = np.asarray(idx_dev)
+                        if ret_dev is not None:
+                            ret_host = np.asarray(ret_dev)
+                with obs.span("worker.pack", jobs=len(group)):
+                    # n_real (the jobs actually computed), NOT
+                    # host.shape[1]: the mesh path pads rows to a chip
+                    # multiple, and a pad row must never be reported as a
+                    # validated-bad job's "result".
+                    n_rows = 0 if host is None else min(host.shape[1],
+                                                        n_real)
+                    if host is not None:
+                        # Combo credit counts only real jobs and is derived
+                        # from each job's GRID, not the result shape — a
+                        # top-k/best_returns group ships k (or 1) rows but
+                        # computed the full grid, and the dispatcher's
+                        # backtests_per_sec credits grid combos too.
+                        self._c_d2h_bytes.inc(host.nbytes)
+                        self._c_backtests.inc(sum(
+                            wire.grid_n_combos(job.grid)
+                            for job in group[:n_rows]))
+                    per_job = (time.perf_counter() - t0) / max(len(group), 1)
+                    for i, job in enumerate(group):
+                        if i < n_rows:
+                            row = Metrics(*(host[k, i] for k in range(9)))
+                            if mode == "topk":
+                                blob = wire.topk_to_bytes(idx_host[i], row,
+                                                          extra[1])
+                            elif mode == "returns":
+                                # Trim to the job's real history: padded
+                                # bars earn exactly zero (repeat-last close
+                                # + held position) but belong to the group,
+                                # not the job.
+                                blob = wire.best_returns_to_bytes(
+                                    int(idx_host[i]), row,
+                                    ret_host[i, :int(lens[i])],
+                                    extra["metric"])
+                            else:
+                                blob = wire.metrics_to_bytes(row)
+                        else:
+                            blob = b""   # validated-bad job: no result
+                        out.append(Completion(job.id, blob, per_job,
+                                              trace_id=job.trace_id))
         return out
 
     def process(self, jobs) -> list[Completion]:

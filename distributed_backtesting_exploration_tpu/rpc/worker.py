@@ -283,12 +283,11 @@ class Worker:
         self._c_batch_failures = self.obs.counter(
             "dbx_worker_batch_failures_total",
             help="batches whose backend submit/collect raised")
-        # Every per-worker-labeled metric (the jobs/sec gauge and the
-        # collector-maintained channel/deferred/busy gauges) is created in
-        # run() and removed in its finally — a constructed-but-never-run
-        # Worker must leak neither a collector closing over itself nor a
-        # uuid-labeled gauge child.
-        self._jobs_rate = obs.StepTimer()
+        # Every per-worker-labeled metric (the collector-maintained
+        # channel/deferred/busy gauges) is created in run() and removed
+        # in its finally — a constructed-but-never-run Worker must leak
+        # neither a collector closing over itself nor a uuid-labeled
+        # gauge child.
         self._gauges: dict | None = None
         # Substrate-autotuner + fleet-compile-cache sync (tune/, round
         # 11): attached in run() only for backends that expose a schedule
@@ -352,9 +351,18 @@ class Worker:
             # pipelined path is verified against.
             self._compute_loop_simple()
 
+    def _take_batch(self):
+        """The compute thread's blocking take from the input channel, as
+        the ``worker.wait_batch`` span: while it is open the compute side
+        is starved, waiting on the control thread for work."""
+        with obs.span("worker.wait_batch") as attrs:
+            batch = self._in.get()
+            attrs["jobs"] = len(batch) if batch is not None else 0
+        return batch
+
     def _compute_loop_simple(self) -> None:
         while True:
-            batch = self._in.get()
+            batch = self._take_batch()
             if batch is None:
                 return
             # The shared pipeline accounting drives the busy flag here
@@ -368,8 +376,7 @@ class Worker:
                 with obs.trace_context(obs.job_trace_pairs(batch)), \
                         obs.span("worker.process", jobs=len(batch),
                                  worker=self.worker_id):
-                    for completion in self.backend.process(batch):
-                        self._out.put(completion)
+                    self._hand_off(self.backend.process(batch))
             except Exception as e:
                 self._backend_failed(batch, e)
             finally:
@@ -410,10 +417,13 @@ class Worker:
         collector.start()
         try:
             while True:
-                batch = self._in.get()
+                batch = self._take_batch()
                 if batch is None:
                     return
-                slots.acquire()
+                # Backpressure from the collect lane: every slot is held
+                # by a submitted batch not yet collected.
+                with obs.span("worker.wait_slot", jobs=len(batch)):
+                    slots.acquire()
                 self._pipeline_batch_begin()
                 pending = self._try_submit(batch)
                 if pending is None:
@@ -499,10 +509,18 @@ class Worker:
             with obs.trace_context(obs.job_trace_pairs(batch)), \
                     obs.span("worker.collect", jobs=len(batch),
                              worker=self.worker_id):
-                for completion in self.backend.collect(handle):
-                    self._out.put(completion)
+                self._hand_off(self.backend.collect(handle))
         except Exception as e:
             self._backend_failed(batch, e)
+
+    def _hand_off(self, completions) -> None:
+        """Encode each completion onto the out channel for the control
+        thread: the last step of packing the batch's results, so it
+        shares the backend's ``worker.pack`` span name."""
+        completions = list(completions)
+        with obs.span("worker.pack", jobs=len(completions)):
+            for completion in completions:
+                self._out.put(completion)
 
     def _backend_failed(self, batch, e: Exception) -> None:
         """The one handler for a backend exception on any compute path:
@@ -550,19 +568,11 @@ class Worker:
             self._telemetry = obs_fleet.WorkerTelemetry(
                 self.worker_id, stats_fn=self._telemetry_stats,
                 backend=self.backend, registry=self.obs)
-        # Fresh timer epoch: the rate is "since the worker STARTED", not
-        # since it was constructed (a harness may build workers long
-        # before running them).
         # The per-worker label set is a deliberate, BOUNDED exception to
         # the obs-cardinality rule: one process hosts a handful of workers
         # and every uuid-labeled child is removed in this method's finally
         # (lifecycle hygiene below), so the series count tracks LIVE
         # workers, not all workers ever seen.
-        # dbxlint: disable=obs-cardinality -- lifecycle-managed: removed in run()'s finally
-        self._jobs_rate = obs.StepTimer(self.obs.gauge(
-            "dbx_worker_jobs_per_sec",
-            help="accepted completions/s since worker start",
-            worker=self.worker_id))
         wid = self.worker_id
         self._gauges = {
             # dbxlint: disable=obs-cardinality -- lifecycle-managed: removed in run()'s finally
@@ -626,9 +636,7 @@ class Worker:
             # every scrape, GetStats payload, and BENCH obs blob would
             # carry them forever.
             self.obs.remove_collector(f"worker-{self.worker_id}")
-            self._jobs_rate.bind_gauge(None)
             wid = self.worker_id
-            self.obs.remove_child("dbx_worker_jobs_per_sec", worker=wid)
             for ch in ("in", "out"):
                 self.obs.remove_child("dbx_worker_channel_depth",
                                       worker=wid, channel=ch)
@@ -789,7 +797,7 @@ class Worker:
             schedule_json=schedule_json,
             telemetry_json=telemetry_json)
         try:
-            with obs.timer(self._h_rpc["RequestJobs"]):
+            with obs.timer(self._h_rpc["RequestJobs"], name="worker.poll"):
                 reply = stub.RequestJobs(req, timeout=30.0)
             self._log_reconnected()
         except grpc.RpcError as e:
@@ -1015,10 +1023,6 @@ class Worker:
         """One CompleteJobs attempt for ``chunk`` = [(attempts, completion)];
         on RPC failure each item parks for deferred retry (or is dropped
         once its attempts are exhausted — the lease re-queues the job)."""
-        req = pb.CompleteBatch(worker_id=self.worker_id, items=[
-            pb.CompleteItem(id=c.job_id, metrics=c.metrics,
-                            elapsed_s=c.elapsed_s, trace_id=c.trace_id)
-            for _, c in chunk])
         try:
             # Timeout stays under the dispatcher's default 10 s prune window:
             # only ONE batch RPC can delay the next heartbeat (status_overdue
@@ -1027,17 +1031,22 @@ class Worker:
             # park for retry and, if attempts exhaust, leases re-queue them.
             # The report span joins each completion's trace (no remote
             # parent — the dispatch span parented the compute chain; the
-            # report leg is a root-level stage of the job's timeline).
+            # report leg is a root-level stage of the job's timeline). It
+            # covers the whole report: the request's build, then the RPC.
             with obs.trace_context([(c.trace_id, "") for _, c in chunk]), \
                     obs.span("worker.report", jobs=len(chunk),
-                             worker=self.worker_id), \
-                    obs.timer(self._h_rpc["CompleteJobs"]):
-                reply = stub.CompleteJobs(req, timeout=8.0)
+                             worker=self.worker_id):
+                req = pb.CompleteBatch(worker_id=self.worker_id, items=[
+                    pb.CompleteItem(id=c.job_id, metrics=c.metrics,
+                                    elapsed_s=c.elapsed_s,
+                                    trace_id=c.trace_id)
+                    for _, c in chunk])
+                with obs.timer(self._h_rpc["CompleteJobs"]):
+                    reply = stub.CompleteJobs(req, timeout=8.0)
             self._log_reconnected()
             self._c_wire[("CompleteJobs", "request")].inc(_pb_size(req))
             self._c_wire[("CompleteJobs", "reply")].inc(_pb_size(reply))
             self.jobs_completed += reply.accepted
-            self._jobs_rate.add(reply.accepted)
             for jid in reply.unknown_ids:
                 log.warning("completion %s rejected: unknown job", jid)
         except grpc.RpcError as e:

@@ -334,6 +334,23 @@ def test_merged_stage_histogram_is_exact_vs_single_registry():
     assert h.sum == pytest.approx(merged["sum_s"])
 
 
+def test_collect_lane_spans_fold_onto_execute_and_d2h():
+    """The collect lane's split: the device finishing a group costs out
+    as execute, the copy back and the packing as d2h; the compute
+    thread's waits and the envelopes cost out as no stage."""
+    st = fleet._StageStats()
+    for name, dur in (("worker.device_wait", 0.25), ("worker.d2h", 0.125),
+                      ("worker.pack", 0.5), ("worker.wait_batch", 2.0),
+                      ("worker.wait_slot", 1.0), ("worker.collect", 1.0)):
+        st.observe({"name": name, "dur_s": dur})
+    snap = st.snapshot()
+    assert snap["execute"]["n"] == 1
+    assert snap["execute"]["sum_s"] == pytest.approx(0.25)
+    assert snap["d2h"]["n"] == 2
+    assert snap["d2h"]["sum_s"] == pytest.approx(0.625)
+    assert snap["decode"]["n"] == snap["compile"]["n"] == 0
+
+
 def test_cohosted_workers_fold_once_per_pid():
     """Co-hosted workers share one process-scope span stream; the fold
     dedupes per process so a 2-workers-1-process bench cannot

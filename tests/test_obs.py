@@ -198,6 +198,106 @@ def test_span_records_on_exception(tmp_path):
     assert rec["name"] == "boom" and rec["ok"] is False
 
 
+def test_span_attrs_set_inside_the_block_reach_the_record():
+    """A span hands its block the attrs dict: an attr known only inside
+    (the size of the batch a wait returned) lands on the record."""
+    with obs.span("attr_probe") as attrs:
+        attrs["jobs"] = 7
+    rec = next(r for r in reversed(obs.recent_spans())
+               if r["name"] == "attr_probe")
+    assert rec["jobs"] == 7
+
+
+def _host_events(xplane_path, names):
+    """``name -> [(start_ns, end_ns)]`` of the trace's host events."""
+    from jax.profiler import ProfileData
+
+    out = {n: [] for n in names}
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in out:
+                    out[ev.name].append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_span_and_named_timer_are_host_events_in_a_profiler_trace(tmp_path):
+    """While a jax.profiler trace runs, a span and a named timer are host
+    events of their names on the profiler's clock, the inner one inside
+    the outer one; the timer still observes only its own histogram."""
+    import glob
+    import os
+    import time
+
+    import jax
+
+    reg = obs.Registry()
+    hist = reg.histogram("dbx_probe_seconds")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.span("probe.outer"):
+            time.sleep(0.005)
+            with obs.timer(hist, name="probe.inner"):
+                time.sleep(0.005)
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    ev = _host_events(path, ("probe.outer", "probe.inner"))
+    assert len(ev["probe.outer"]) == 1 and len(ev["probe.inner"]) == 1
+    (o0, o1), (i0, i1) = ev["probe.outer"][0], ev["probe.inner"][0]
+    assert o0 <= i0 < i1 <= o1
+    assert i1 - i0 >= 4e6 and o1 - o0 >= i1 - i0 + 8e6
+    assert hist.summary()["count"] == 1
+    # The named timer made no span record.
+    assert not any(r["name"] == "probe.inner" for r in obs.recent_spans())
+    # With the trace stopped, a span opens no annotation.
+    from distributed_backtesting_exploration_tpu.obs import trace as obs_trace
+
+    assert obs_trace._annotation_cls is jax.profiler.TraceAnnotation
+    assert obs_trace._annotation("probe.after") is None
+
+
+def test_obs_without_jax_annotates_nothing():
+    """obs imports no jax, and in a process without jax a span and a
+    named timer annotate nothing (a dispatcher-only process). The package
+    root imports jax, so the child loads obs below a bare package."""
+    import os
+    import subprocess
+    import sys
+
+    import distributed_backtesting_exploration_tpu as dbx
+
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('distributed_backtesting_exploration_tpu')\n"
+        f"pkg.__path__ = [{os.path.dirname(dbx.__file__)!r}]\n"
+        "sys.modules[pkg.__name__] = pkg\n"
+        "from distributed_backtesting_exploration_tpu import obs\n"
+        "from distributed_backtesting_exploration_tpu.obs import trace\n"
+        "with obs.span('outer'):\n"
+        "    with obs.timer(obs.get_registry().histogram("
+        "'dbx_probe_seconds'), name='inner'):\n"
+        "        pass\n"
+        "loaded = [m for m in sys.modules\n"
+        "          if m.split('.')[0] in ('jax', 'jaxlib')]\n"
+        "assert not loaded, loaded\n"
+        "assert trace._annotation_cls is None\n"
+        "assert trace._annotation('x') is None\n"
+        "print('ok', obs.get_registry().summaries()"
+        "['dbx_span_seconds{span=outer}']['count'])\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["ok", "1"]
+
+
 def test_span_trace_ids_context_and_ring(tmp_path):
     """Every span carries a (trace_id, span_id, parent_id) triple: nested
     spans parent locally, the outermost span of a trace_context adopts the
@@ -490,10 +590,24 @@ def test_timeline_inflight_span_charges_execute_not_transport():
          "trace_id": tid, "span_id": "s3"},
         {"ev": "span", "name": "worker.collect", "t0": 3.0, "dur_s": 1.0,
          "trace_id": tid, "span_id": "s4"},
+        # The collect lane's split: the device finishing the group is
+        # execute, the copy back and the packing are d2h.
+        {"ev": "span", "name": "worker.device_wait", "t0": 3.0,
+         "dur_s": 0.25, "trace_id": tid, "span_id": "s5", "parent_id": "s4"},
+        {"ev": "span", "name": "worker.d2h", "t0": 3.25, "dur_s": 0.25,
+         "trace_id": tid, "span_id": "s6", "parent_id": "s4"},
+        {"ev": "span", "name": "worker.pack", "t0": 3.5, "dur_s": 0.4,
+         "trace_id": tid, "span_id": "s7", "parent_id": "s4"},
+        # The compute thread's waits carry no trace: no stage of any job.
+        {"ev": "span", "name": "worker.wait_batch", "t0": 0.5,
+         "dur_s": 0.5, "span_id": "s8"},
     ]
-    stages = timeline.critical_path(timeline.reconstruct(spans)[tid])
-    assert stages["execute"] == pytest.approx(2.0)   # submit + inflight
-    assert stages["d2h"] == pytest.approx(1.0)
+    tls = timeline.reconstruct(spans)
+    assert set(tls) == {tid}
+    stages = timeline.critical_path(tls[tid])
+    # submit + inflight + the device wait
+    assert stages["execute"] == pytest.approx(2.25)
+    assert stages["d2h"] == pytest.approx(0.75)   # d2h + pack + envelope
     assert stages["transport"] == pytest.approx(0.5)  # only the real gap
     assert sum(stages.values()) == pytest.approx(4.0)
 
@@ -538,6 +652,13 @@ def test_timeline_overlap_factor_pipelined_vs_serial():
                  "dur_s": 0.5, "trace_id": tid_a, "span_id": "a-inf"},
                 {"ev": "span", "name": "worker.collect", "t0": 2.5,
                  "dur_s": 1.5, "trace_id": tid_a, "span_id": "a-col"},
+                # A's collect split: all inside the collect lane already.
+                {"ev": "span", "name": "worker.device_wait", "t0": 2.5,
+                 "dur_s": 0.5, "trace_id": tid_a, "span_id": "a-dw"},
+                {"ev": "span", "name": "worker.d2h", "t0": 3.0,
+                 "dur_s": 0.25, "trace_id": tid_a, "span_id": "a-d2h"},
+                {"ev": "span", "name": "worker.pack", "t0": 3.25,
+                 "dur_s": 0.75, "trace_id": tid_a, "span_id": "a-pack"},
                 # B's submit overlaps A's collect drain: the pipeline.
                 {"ev": "span", "name": "worker.submit", "t0": 2.0,
                  "dur_s": 2.0, "trace_id": tid_b, "span_id": "b-sub"},

@@ -1229,7 +1229,9 @@ def test_obs_pipelined_span_chain_and_kernel_attribution(tmp_path):
     assert s["dbx_span_seconds{span=worker.report}"]["count"] > 0
     assert s["dbx_compute_decode_seconds"]["count"] > 0
     assert s["dbx_compute_decode_bytes_total"] > 0
-    assert s["dbx_compute_collect_seconds"]["count"] > 0
+    # The collect lane, split per group: device wait, copy back, packing.
+    for phase in ("device_wait", "d2h", "pack"):
+        assert s[f"dbx_span_seconds{{span=worker.{phase}}}"]["count"] > 0
     assert s["dbx_compute_d2h_bytes_total"] > 0
     # per-strategy kernel wall keyed by route:strategy, compile/execute split
     kern = [k for k in s if k.startswith("dbx_kernel_submit_seconds")
@@ -1294,6 +1296,53 @@ def test_obs_pipelined_span_chain_and_kernel_attribution(tmp_path):
     # CLI smoke over the real log: text + json, --job filter.
     assert timeline.main(["--jsonl", jsonl, "--format", "json",
                           "--job", jobs[0].id]) == 0
+
+
+def test_fused_batch_collect_lane_spans():
+    """One fused batch through an in-process worker: the compute thread's
+    wait for the batch, then — inside ``worker.collect`` — the device
+    wait, the copy back and the packing, each a span of the whole
+    batch (``jobs``), never one per job."""
+    from distributed_backtesting_exploration_tpu import obs
+
+    obs.configure_ring(4096)
+    queue = JobQueue()
+    jobs = synthetic_jobs(4, 64, "sma_crossover", GRID)
+    for rec in jobs:
+        queue.enqueue(rec)
+    disp, srv = _server(queue)
+    try:
+        w, t = _run_worker(f"localhost:{srv.port}",
+                           compute.JaxSweepBackend(use_fused=True),
+                           jobs_per_chip=4)
+        _wait(lambda: queue.drained, msg="queue drained")
+        t.join(timeout=10)
+    finally:
+        srv.stop()
+        ring = obs.recent_spans()
+        obs.configure_ring()
+    assert w.jobs_completed == 4 and w.batches_failed == 0
+    # The batch's collect span, and the lane's spans that carry its trace.
+    collect = [r for r in ring if r["name"] == "worker.collect"
+               and r.get("jobs") == 4]
+    assert len(collect) == 1
+    col = collect[0]
+    inside = {r["name"]: r for r in ring
+              if r.get("parent_id") == col["span_id"]}
+    assert {"worker.device_wait", "worker.d2h", "worker.pack"} <= set(inside)
+    for name in ("worker.device_wait", "worker.d2h", "worker.pack"):
+        r = inside[name]
+        assert r["parent"] == "worker.collect" and r["jobs"] == 4
+        assert col["t0"] <= r["t0"]
+        assert r["t0"] + r["dur_s"] <= col["t0"] + col["dur_s"] + 1e-3
+    assert "cache_hit" in inside["worker.d2h"]
+    lane = sum(inside[n]["dur_s"]
+               for n in ("worker.device_wait", "worker.d2h"))
+    assert lane <= col["dur_s"]
+    # The wait that took this batch off the input channel.
+    waits = [r for r in ring if r["name"] == "worker.wait_batch"]
+    assert any(r.get("jobs") == 4 for r in waits)
+    assert all("trace_id" not in r and "traces" not in r for r in waits)
 
 
 @pytest.mark.slow   # subprocess worker + real cross-process log merge
