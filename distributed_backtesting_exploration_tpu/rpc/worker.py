@@ -544,8 +544,7 @@ class Worker:
         that many consecutive empty replies once at least one job was seen.
         """
         channel = grpc.insecure_channel(
-            self.target, options=service.default_channel_options(),
-            compression=grpc.Compression.Gzip)
+            self.target, options=service.default_channel_options())
         stub = service.DispatcherStub(channel)
         if getattr(self.backend, "panel_cache", None) is not None:
             # Compute-thread recovery hook for the evicted-between-poll-

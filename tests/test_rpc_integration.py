@@ -89,6 +89,47 @@ def test_end_to_end_instant_backend(tmp_path):
         srv.stop()
 
 
+@pytest.mark.parametrize("compression", [grpc.Compression.Gzip, None],
+                         ids=["gzip", "plain"])
+def test_complete_jobs_blocks_identical_from_gzip_and_plain_channels(
+        compression):
+    """A client that still gzips its requests (an older worker in a rolling
+    upgrade) and one on the plain channel both land byte-identical result
+    blocks: the dispatcher decodes each message by its own grpc-encoding."""
+    from distributed_backtesting_exploration_tpu.ops.metrics import Metrics
+
+    queue = JobQueue()
+    recs = synthetic_jobs(3, 32, "sma_crossover", GRID)
+    for rec in recs:
+        queue.enqueue(rec)
+    disp, srv = _server(queue)
+    rng = np.random.default_rng(24)
+    channel = grpc.insecure_channel(
+        f"localhost:{srv.port}", options=service.default_channel_options(),
+        compression=compression)
+    try:
+        stub = service.DispatcherStub(channel)
+        reply = stub.RequestJobs(pb.JobsRequest(
+            worker_id="w", chips=1, jobs_per_chip=len(recs)))
+        assert len(reply.jobs) == len(recs)
+        blocks = {}
+        for job in reply.jobs:
+            combos = wire.grid_n_combos(job.grid)
+            blocks[job.id] = wire.metrics_to_bytes(Metrics(*(
+                rng.standard_normal(combos).astype(np.float32)
+                for _ in Metrics._fields)))
+        crep = stub.CompleteJobs(pb.CompleteBatch(
+            worker_id="w",
+            items=[pb.CompleteItem(id=jid, metrics=block)
+                   for jid, block in blocks.items()]))
+    finally:
+        channel.close()
+        srv.stop()
+    assert crep.accepted == len(recs) and not crep.unknown_ids
+    assert {jid: bytes(disp.results[jid]) for jid in blocks} == blocks
+    assert queue.drained
+
+
 def test_jax_backend_fused_ragged_batch_matches_direct():
     """A mixed-length job batch stays on the fused path (use_fused=True,
     interpret mode on CPU) and matches per-job direct sweeps — the routing

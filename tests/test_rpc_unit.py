@@ -555,6 +555,49 @@ def test_completion_dropped_after_attempts_exhausted():
     assert w.completions_dropped == 1 and not w._deferred
 
 
+def test_worker_channel_asks_for_no_compression(monkeypatch):
+    """Requests go out uncompressed (rpc/service.py: gzip of result blocks
+    costs more CPU than the bytes it saves): neither the shared channel
+    options nor the channel ``Worker.run`` opens may ask for compression."""
+    import grpc
+
+    from distributed_backtesting_exploration_tpu.rpc import compute, service
+    from distributed_backtesting_exploration_tpu.rpc.worker import Worker
+
+    assert not any("compression" in key
+                   for key, _ in service.default_channel_options())
+    w = Worker("localhost:1", compute.InstantBackend())
+    opened = []
+
+    class StoppingChannel:
+        """Every RPC stops the worker and fails: run() ends after one loop."""
+
+        def unary_unary(self, *args, **kwargs):
+            return self._call
+
+        unary_stream = unary_unary
+
+        def _call(self, *args, **kwargs):
+            w.stop()
+            raise grpc.RpcError()
+
+        def close(self):
+            pass
+
+    def insecure_channel(target, *args, **kwargs):
+        opened.append((target, args, kwargs))
+        return StoppingChannel()
+
+    monkeypatch.setattr(grpc, "insecure_channel", insecure_channel)
+    w.run()
+    [(target, args, kwargs)] = opened
+    assert target == "localhost:1"
+    options = kwargs.get("options", args[0] if args else ())
+    assert not any("compression" in key for key, _ in options)
+    assert kwargs.get("compression") in (
+        None, grpc.Compression.NoCompression)
+
+
 def test_native_substrate_defaults(monkeypatch):
     """The C++ core backs the live paths where it measures fastest: the
     registry and worker channels default native; the job-queue state
