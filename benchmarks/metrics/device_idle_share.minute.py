@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - union of device op intervals / window), averaged over the cell's
+chips, in percent."""
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * run.trace.idle_share
