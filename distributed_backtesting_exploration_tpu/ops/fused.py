@@ -32,6 +32,7 @@ import contextlib
 import functools
 import os
 import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +83,11 @@ _WIDE_BLOCK_BYTES = 6 * 1024 * 1024
 # tests/test_tpu_compile.py compiles the cap for a described v5e.
 # Ignored in interpret mode.
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+# The same limit for a cell that walks T in blocks (`_TBlock`): its T axis
+# carries state from block to block, so it runs in order.
+_T_BLOCKED_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=100 * 1024 * 1024,
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------------------
@@ -450,9 +456,13 @@ def _interp_epilogue(epilogue: str, T_pad: int, interpret: bool) -> str:
     return f"scan:{_round_up(T_pad, 8)}"
 
 
-def _equity_scan(net, block: int):
-    """``(mdd, eq_final)`` of ``equity = 1 + cumsum(net)`` in one
-    sequential pass over T-blocks.
+def _equity_scan(net, block: int, carry=None):
+    """``(mdd, cum, peak)`` rows of ``equity = 1 + cumsum(net)`` in one
+    sequential pass over T-blocks: the max drawdown, the cumulative net
+    return (the final equity is ``1 + cum``) and the running-max equity.
+    ``carry`` continues a scan an earlier stretch of the history left
+    as those three ``(1, lanes)`` rows (a T-blocked launch,
+    :class:`_TBlock`); None starts from ``0 / 0 / -inf``.
 
     Carries: the cumulative net return and the running-max equity, both
     ``(1, lanes)`` rows threaded between blocks. Per block the local
@@ -471,9 +481,12 @@ def _equity_scan(net, block: int):
     re-blocking or reassociating edit here fails the drift gate with
     the introducing equation chain."""
     T_pad, lanes = net.shape
-    carry = jnp.zeros((1, lanes), jnp.float32)
-    peak_c = jnp.full((1, lanes), -jnp.inf, jnp.float32)
-    mdd = jnp.zeros((1, lanes), jnp.float32)
+    if carry is None:
+        carry = jnp.zeros((1, lanes), jnp.float32)
+        peak_c = jnp.full((1, lanes), -jnp.inf, jnp.float32)
+        mdd = jnp.zeros((1, lanes), jnp.float32)
+    else:
+        mdd, carry, peak_c = carry
     for s, e in _spans(T_pad, block):
         cs = _cumsum0(net[s:e])
         eq = (1.0 + carry) + cs
@@ -482,7 +495,7 @@ def _equity_scan(net, block: int):
         mdd = jnp.maximum(mdd, jnp.max(dd, axis=0, keepdims=True))
         carry = carry + cs[e - s - 1:]
         peak_c = peak[e - s - 1:]
-    return mdd[0], 1.0 + carry[0]
+    return mdd, carry, peak_c
 
 
 def _cumsum_last(x):
@@ -578,8 +591,59 @@ def _row_at(x, tr, t_idx, *, keepdims: bool):
                    keepdims=keepdims)
 
 
+# ---------------------------------------------------------------------------
+# T-blocked cells
+#
+# A history longer than one VMEM-sized block walks T as the innermost,
+# sequential grid axis (`_band_machine_pallas`): each step reads one
+# ``(1, T_blk, .)`` block of the returns and the z-table, and the rows of
+# one ``(16, lanes)`` VMEM scratch carry what the next block needs — the
+# band machine's last position (its latch state and the next block's
+# ``pos_{t-1}``) and every running accumulator of the shared epilogue
+# (`_metrics_pack`), the in-kernel twin of ``streaming.recurrent``'s
+# append carry. The metric rows written at the last block are final.
+# ---------------------------------------------------------------------------
+
+# Carry rows of the T-blocked scratch.
+_C_POS, _C_CUM, _C_PEAK, _C_MDD = 0, 1, 2, 3
+_C_S1, _C_S2, _C_DOWN, _C_WINS, _C_ACTIVE, _C_TURN = 4, 5, 6, 7, 8, 9
+
+
+class _TBlock(NamedTuple):
+    """Where a T-blocked cell is in the history: ``carry`` is its
+    ``(16, lanes)`` VMEM scratch ref (rows ``_C_*``), ``t0`` the first
+    bar of this block."""
+    carry: object
+    t0: object
+
+
+def _t_block_of(refs, T_blk: int):
+    """Split a T-blocked cell's carry scratch off the end of its ``refs``
+    and reset it at the history's first block: ``(refs, _TBlock)``."""
+    *head, carry = refs
+    t = pl.program_id(2)
+
+    @pl.when(t == 0)
+    def _start():
+        row = jax.lax.broadcasted_iota(jnp.int32, carry.shape, 0)
+        carry[...] = jnp.where(row == _C_PEAK, -jnp.inf, 0.0)
+
+    return tuple(head), _TBlock(carry, t * T_blk)
+
+
+def _carried(tb, row: int, x):
+    """``x``, a ``(lanes,)`` sum over this block's bars, plus what the
+    earlier blocks summed in carry row ``row`` (stored back); ``x`` itself
+    in a one-block cell (``tb`` None)."""
+    if tb is None:
+        return x
+    total = tb.carry[row:row + 1, :] + x[None, :]
+    tb.carry[row:row + 1, :] = total
+    return total[0]
+
+
 def _metrics_tail(pos, r, t_idx, tr, *, cost: float, ppy: int,
-                  epilogue: str = _EPILOGUE_DEFAULT):
+                  epilogue: str = _EPILOGUE_DEFAULT, tb=None):
     """Shared kernel tail: positions -> packed (16, 128) metric rows.
 
     ``pos`` is the per-lane position path over ``(T_pad, 128)`` (any signal
@@ -589,51 +653,78 @@ def _metrics_tail(pos, r, t_idx, tr, *, cost: float, ppy: int,
     reduction over T_pad equals the unpadded reduction over tr (zero
     return, zero turnover in the pad). ``epilogue`` picks the equity/
     drawdown substrate (see `_equity_scan` / `_resolve_epilogue`).
+
+    ``tb`` (a T-blocked cell, :class:`_TBlock`): ``pos`` and ``t_idx``
+    cover one block; the bar before it and the held final position come
+    from the carry, and the rows returned are final at the last block.
     """
     row_ok = t_idx < tr
-    pos_last = _row_at(pos, tr, t_idx, keepdims=True)
-    pos = jnp.where(row_ok, pos, pos_last)
-
-    prev = _shift_down(pos, 1, 0.0)
+    if tb is None:
+        pos_last = _row_at(pos, tr, t_idx, keepdims=True)
+        pos = jnp.where(row_ok, pos, pos_last)
+        prev = _shift_down(pos, 1, 0.0)
+    else:
+        # The last real bar lies in this block or an earlier one, whose
+        # last (held) position the carry keeps.
+        held = tb.carry[_C_POS:_C_POS + 1, :]
+        in_block = jnp.sum(jnp.where(t_idx == tr - 1, pos, 0.0), axis=0,
+                           keepdims=True)
+        pos = jnp.where(row_ok, pos,
+                        jnp.where(tr - 1 < tb.t0, held, in_block))
+        prev = jnp.concatenate([held, pos[:-1]], axis=0)
     net = prev * r - cost * jnp.abs(pos - prev)
-    return _metrics_pack(pos, prev, net, row_ok, t_idx, tr, ppy=ppy,
-                         epilogue=epilogue)
+    rows = _metrics_pack(pos, prev, net, row_ok, t_idx, tr, ppy=ppy,
+                         epilogue=epilogue, tb=tb)
+    if tb is not None:
+        tb.carry[_C_POS:_C_POS + 1, :] = pos[-1:]
+    return rows
 
 
 def _metrics_pack(pos, prev, net, row_ok, t_idx, tr, *, ppy: int,
-                  epilogue: str = _EPILOGUE_DEFAULT):
+                  epilogue: str = _EPILOGUE_DEFAULT, tb=None):
     """Reduce per-bar ``net``/positions to the packed (16, 128) metric rows.
 
     Callers guarantee the padding discipline: ``pos`` holds its final real
     value for ``t >= tr`` and ``net`` is exactly zero there, so plain
-    reductions over T_pad equal the unpadded reductions over tr.
+    reductions over T_pad equal the unpadded reductions over tr. In a
+    T-blocked cell (``tb``) every reduction continues the carried one.
     """
     n = jnp.asarray(tr, jnp.float32)
-    s1 = jnp.sum(net, axis=0)
-    s2 = jnp.sum(net * net, axis=0)
+    s1 = _carried(tb, _C_S1, jnp.sum(net, axis=0))
+    s2 = _carried(tb, _C_S2, jnp.sum(net * net, axis=0))
     mean = s1 / n
     var = jnp.maximum(s2 / n - mean * mean, 0.0)
     std = jnp.sqrt(var)
     ann = jnp.sqrt(jnp.float32(ppy))
     down = jnp.minimum(net, 0.0)
-    dstd = jnp.sqrt(jnp.sum(down * down, axis=0) / n)
+    dstd = jnp.sqrt(_carried(tb, _C_DOWN, jnp.sum(down * down, axis=0)) / n)
 
-    if epilogue == "ladder":
+    if epilogue == "ladder" and tb is None:
         equity = 1.0 + _cumsum0(net)
         peak = _cummax0(equity)
         dd = (peak - equity) / jnp.maximum(peak, _EPS)
         mdd = jnp.max(jnp.where(row_ok, dd, 0.0), axis=0)
         eq_final = _row_at(equity, tr, t_idx, keepdims=False)
     else:
-        mdd, eq_final = _equity_scan(
-            net, _scan_block(net.shape[0], epilogue))
+        # A T-blocked ladder cell scans its block as one ladder.
+        block = (net.shape[0] if epilogue == "ladder"
+                 else _scan_block(net.shape[0], epilogue))
+        rows = (_C_MDD, _C_CUM, _C_PEAK)
+        mdd, cum, _peak = _equity_scan(
+            net, block, None if tb is None else
+            tuple(tb.carry[i:i + 1, :] for i in rows))
+        if tb is not None:
+            for i, v in zip(rows, (mdd, cum, _peak)):
+                tb.carry[i:i + 1, :] = v
+        mdd, eq_final = mdd[0], 1.0 + cum[0]
 
     active = (jnp.abs(prev) > 0) & row_ok
     wins = (net > 0) & active
-    hit = jnp.sum(wins.astype(jnp.float32), axis=0) / (
-        jnp.sum(active.astype(jnp.float32), axis=0) + _EPS)
+    hit = _carried(tb, _C_WINS, jnp.sum(wins.astype(jnp.float32), axis=0)) / (
+        _carried(tb, _C_ACTIVE, jnp.sum(active.astype(jnp.float32), axis=0))
+        + _EPS)
 
-    turnover = jnp.sum(jnp.abs(pos - prev), axis=0)
+    turnover = _carried(tb, _C_TURN, jnp.sum(jnp.abs(pos - prev), axis=0))
     years = jnp.maximum(n / jnp.float32(ppy), _EPS)
     final = jnp.maximum(eq_final, _EPS)
 
@@ -975,9 +1066,15 @@ def _prefix_compose3(pm, p0, pp):
     return pm, p0, pp
 
 
-def _compose3_path(pm, p0, pp, epilogue: str):
+def _select3(state, m, z, p):
+    """The component of composed 3-state maps for entry ``state``."""
+    return jnp.where(state < 0, m, jnp.where(state > 0, p, z))
+
+
+def _compose3_path(pm, p0, pp, epilogue: str, state=None):
     """Position path of a 3-state machine from its per-bar transition maps,
-    starting flat.
+    starting flat, or from the ``(1, lanes)`` ``state`` an earlier T block
+    ended in (a T-blocked cell).
 
     ``"ladder"``: the full-T doubling ladder (`_prefix_compose3`), O(T log T).
     ``"scan"`` (default): ONE sequential pass over T-blocks — each block's
@@ -989,34 +1086,36 @@ def _compose3_path(pm, p0, pp, epilogue: str):
     ~55%-of-tail compose cost (the 179-vs-76 ``vpu_ops_per_cell_bar``
     spread vs the sign kernels) drops to the sign kernels' class."""
     if epilogue == "ladder":
-        _, p0, _ = _prefix_compose3(pm, p0, pp)
-        return p0   # start state is flat: the 0-component is the path
+        pm, p0, pp = _prefix_compose3(pm, p0, pp)
+        # From flat the 0-component is the path.
+        return p0 if state is None else _select3(state, pm, p0, pp)
     T_pad = pm.shape[0]
-    state = None
     outs = []
     for s, e in _spans(T_pad, _scan_block(T_pad, epilogue)):
         m, z, p = _prefix_compose3(pm[s:e], p0[s:e], pp[s:e])
-        pos = z if state is None else jnp.where(
-            state < 0, m, jnp.where(state > 0, p, z))
+        pos = z if state is None else _select3(state, m, z, p)
         outs.append(pos)
         state = pos[e - s - 1:]
     return jnp.concatenate(outs, axis=0)
 
 
-def _band_ladder(z, valid, k, z_exit, epilogue: str = _EPILOGUE_DEFAULT):
+def _band_ladder(z, valid, k, z_exit, epilogue: str = _EPILOGUE_DEFAULT,
+                 state=None):
     """Band-hysteresis position path over ``(T_pad, 128)`` tiles, in-kernel.
 
-    ``k``/``z_exit`` broadcast against the tile (scalars or (1, 128) lanes).
+    ``k``/``z_exit`` broadcast against the tile (scalars or (1, 128) lanes);
+    ``state`` is the position a T-blocked cell's earlier blocks ended in.
     """
     # Per-bar transition maps (next state when previous state is -1/0/+1).
     entered = jnp.where(z < -k, 1.0, jnp.where(z > k, -1.0, 0.0))
     pm = jnp.where(valid & (z > z_exit), -1.0, 0.0)
     p0 = jnp.where(valid, entered, 0.0)
     pp = jnp.where(valid & (z < -z_exit), 1.0, 0.0)
-    return _compose3_path(pm, p0, pp, epilogue)
+    return _compose3_path(pm, p0, pp, epilogue, state)
 
 
-def _band_cell_core(z_wt, r_ref, ow_ref, k_ref, warm_ref, refs, T_real):
+def _band_cell_core(z_wt, r_ref, ow_ref, k_ref, warm_ref, refs, T_real,
+                    t_blocked: bool = False):
     """Shared head of every band-family cell (Bollinger hysteresis, band
     touch; RSI and VWAP reuse those kernels): ragged/uniform unpack, the
     z-selection matmul, warmup mask and band lanes.
@@ -1026,11 +1125,16 @@ def _band_cell_core(z_wt, r_ref, ow_ref, k_ref, warm_ref, refs, T_real):
     lanes, so HBM tiling pads W to a sublane multiple (8) instead of a
     lane multiple (128); at the baseline grid's ~20 distinct windows the
     old (T, W)-minor layout inflated every table and prep intermediate
-    6.4x (same fix as the pairs kernel). Returns
-    ``(tr, out_ref, r, z, t_idx, valid, k)``.
+    6.4x (same fix as the pairs kernel). A T-blocked cell
+    (``t_blocked``) sees one block of the history, its bars numbered from
+    the block's first. Returns ``(tr, out_ref, r, z, t_idx, valid, k,
+    tb)``, ``tb`` the :class:`_TBlock` (None in a one-block cell).
     """
-    tr, out_ref = _unpack_tr(refs, T_real)
     T_pad = r_ref.shape[1]
+    tb = None
+    if t_blocked:
+        refs, tb = _t_block_of(refs, T_pad)
+    tr, out_ref = _unpack_tr(refs, T_real)
     r = r_ref[0]                     # (T_pad, 1)
     dn = (((0,), (0,)), ((), ()))
     z = jax.lax.dot_general(z_wt, ow_ref[:], dn,
@@ -1039,58 +1143,68 @@ def _band_cell_core(z_wt, r_ref, ow_ref, k_ref, warm_ref, refs, T_real):
 
     lanes = ow_ref.shape[1]
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (T_pad, lanes), 0)
+    if tb is not None:
+        t_idx = t_idx + tb.t0
     warm = warm_ref[0, :][None, :]
     valid = t_idx >= (warm.astype(jnp.int32) - 1)
     k = k_ref[0, :][None, :]                         # (1, lanes) entry band
-    return tr, out_ref, r, z, t_idx, valid, k
+    return tr, out_ref, r, z, t_idx, valid, k, tb
 
 
-def _band_cell_prologue(r_ref, z_ref, ow_ref, k_ref, warm_ref, refs, T_real):
+def _band_cell_prologue(r_ref, z_ref, ow_ref, k_ref, warm_ref, refs, T_real,
+                        t_blocked: bool = False):
     """`_band_cell_core` over an HBM-streamed ``(1, W_pad, T_pad)`` block."""
     return _band_cell_core(z_ref[0], r_ref, ow_ref, k_ref, warm_ref, refs,
-                           T_real)
+                           T_real, t_blocked)
 
 
 def _band_cell_finish(machine: str, z, valid, k, z_exit, r, t_idx, tr,
-                      out_ref, *, cost: float, ppy: int, epilogue: str):
+                      out_ref, *, cost: float, ppy: int, epilogue: str,
+                      tb=None):
     """Tail of both Bollinger-family cells — one body for both table
     substrates so the position semantics cannot drift between them.
 
     ``"hysteresis"``: the 3-state band machine (enter outside ±k, exit
     through ±z_exit). ``"touch"``: memoryless — exposure is which band
     you are currently outside of (``models.bollinger.bollinger_touch``),
-    so the compose ladder drops out entirely."""
+    so the compose ladder drops out entirely. A T-blocked cell (``tb``)
+    starts its machine from the carried position and rewrites its output
+    at every block; the last block's rows are final."""
     if machine == "touch":
         pos = jnp.where(z < -k, 1.0, jnp.where(z > k, -1.0, 0.0))
         pos = jnp.where(valid, pos, 0.0)
     else:
-        pos = _band_ladder(z, valid, k, z_exit, epilogue)
+        pos = _band_ladder(z, valid, k, z_exit, epilogue,
+                           None if tb is None
+                           else tb.carry[_C_POS:_C_POS + 1, :])
     out_ref[0, 0] = _metrics_tail(pos, r, t_idx, tr, cost=cost, ppy=ppy,
-                                  epilogue=epilogue)
+                                  epilogue=epilogue, tb=tb)
 
 
 def _boll_kernel(r_ref, z_ref, ow_ref, k_ref, warm_ref, *refs,
                  cost: float, ppy: int, z_exit: float,
-                 T_real: int | None, epilogue: str = _EPILOGUE_DEFAULT):
+                 T_real: int | None, epilogue: str = _EPILOGUE_DEFAULT,
+                 t_blocked: bool = False):
     """Bollinger mean-reversion cell: z-selection matmul + hysteresis
     machine (blocked compose scan by default, see `_compose3_path`)."""
-    tr, out_ref, r, z, t_idx, valid, k = _band_cell_prologue(
-        r_ref, z_ref, ow_ref, k_ref, warm_ref, refs, T_real)
+    tr, out_ref, r, z, t_idx, valid, k, tb = _band_cell_prologue(
+        r_ref, z_ref, ow_ref, k_ref, warm_ref, refs, T_real, t_blocked)
     _band_cell_finish("hysteresis", z, valid, k, z_exit, r, t_idx, tr,
-                      out_ref, cost=cost, ppy=ppy, epilogue=epilogue)
+                      out_ref, cost=cost, ppy=ppy, epilogue=epilogue, tb=tb)
 
 
 def _touch_kernel(r_ref, z_ref, ow_ref, k_ref, warm_ref, *refs,
                   cost: float, ppy: int, z_exit: float,
-                  T_real: int | None, epilogue: str = _EPILOGUE_DEFAULT):
+                  T_real: int | None, epilogue: str = _EPILOGUE_DEFAULT,
+                  t_blocked: bool = False):
     """Band-touch cell: the memoryless Bollinger variant (see
     :func:`_band_cell_finish`). ``z_exit`` is unused (the machine has no
     exit memory); the parameter stays so the kernel is plug-compatible
     with ``_boll_kernel`` in :func:`_fused_boll_call`."""
-    tr, out_ref, r, z, t_idx, valid, k = _band_cell_prologue(
-        r_ref, z_ref, ow_ref, k_ref, warm_ref, refs, T_real)
+    tr, out_ref, r, z, t_idx, valid, k, tb = _band_cell_prologue(
+        r_ref, z_ref, ow_ref, k_ref, warm_ref, refs, T_real, t_blocked)
     _band_cell_finish("touch", z, valid, k, z_exit, r, t_idx, tr,
-                      out_ref, cost=cost, ppy=ppy, epilogue=epilogue)
+                      out_ref, cost=cost, ppy=ppy, epilogue=epilogue, tb=tb)
 
 
 def _build_boll_z_scratch(c, cs, csx, csx2, z_scr, windows: tuple,
@@ -1146,7 +1260,7 @@ def _band_kernel_inline(r_ref, c_ref, cs_ref, csx_ref, csx2_ref, ow_ref,
         _build_boll_z_scratch(c_ref[0], cs_ref[0], csx_ref[0], csx2_ref[0],
                               z_scr, windows, W_pad)
 
-    tr, out_ref, r, z, t_idx, valid, k = _band_cell_core(
+    tr, out_ref, r, z, t_idx, valid, k, _ = _band_cell_core(
         z_scr[:], r_ref, ow_ref, k_ref, warm_ref, tuple(head), T_real)
     _band_cell_finish(machine, z, valid, k, z_exit, r, t_idx, tr,
                       out_ref, cost=cost, ppy=ppy, epilogue=epilogue)
@@ -1182,21 +1296,25 @@ def _cumsum_window_tools(windows: tuple, T_pad: int):
     w_f = w_col.astype(jnp.float32)[None]                        # (1,W,1)
     t_row = jnp.arange(T_pad)[None, :]                           # (1,T_pad)
 
-    def windowed_sum(series):                                    # (N,T_pad) ->
+    def windowed_sum(series, *, prefix=None, as_pair=False):     # (N,T_pad) ->
         # Per-window shifted reads as STATIC slice+concat (plain copies
         # XLA fuses), NOT a (W, T_pad)-indexed gather: the gather version
         # of the SMA table measured ~37% of that whole sweep (bench.py
         # roofline_stages), and windowed_sum3 below learned the same
         # lesson earlier. Bit-identical: window rows are compile-time
         # constants, zero-filled for t < w exactly like the old
-        # clipped-gather + in-window mask.
-        hi, lo = rolling.prefix_sum2(series)                     # (N,T_pad)
+        # clipped-gather + in-window mask. ``prefix`` passes the series'
+        # prefix pair ready-made (``rolling.prefix_prod2``'s exact
+        # products); ``as_pair`` keeps each window sum as the
+        # ``rolling.window_sum2`` pair.
+        hi, lo = rolling.prefix_sum2(series) if prefix is None else prefix
 
         def lagged(cs):                                          # (N,W,T_pad)
             return jnp.stack([_shift_t(cs, int(w), 0.0) for w in windows],
                              axis=1)
-        return rolling.window_diff(hi[:, None, :], lo[:, None, :],
-                                   lagged(hi), lagged(lo))
+        combine = rolling.window_sum2 if as_pair else rolling.window_diff
+        return combine(hi[:, None, :], lo[:, None, :], lagged(hi),
+                       lagged(lo))
 
     def windowed_sum3(series):                                   # (N,W,T_pad)
         # Per-row shifted reads as STATIC slices, not take_along_axis: the
@@ -1230,14 +1348,53 @@ def _cumsum_window_tools(windows: tuple, T_pad: int):
     return w_col, w_f, t_row, windowed_sum, windowed_sum3
 
 
+# The longest history a band-machine cell holds in VMEM whole: every
+# family compiles there for the v5e under _COMPILER_PARAMS
+# (tests/test_tpu_compile.py). Past it the cell walks T in blocks of
+# _T_BLOCK_LONG bars, one (T_blk, lanes) f32 tile (2 MiB at 256 lanes)
+# per live array, the working set of a 2,048-bar daily cell.
+_T_BLOCK_MAX = 8192
+_T_BLOCK_LONG = 2048
+# The strategies whose fused kernel is a band-machine cell launched by
+# `_band_machine_pallas`, so takes a history of any length: the router
+# (rpc.compute.bar_capped) caps every other family at _T_BLOCK_MAX.
+T_BLOCKED_FAMILIES = frozenset({"bollinger", "bollinger_touch", "rsi",
+                                "stochastic", "keltner", "vwap_reversion"})
+
+
+def band_t_pad(T: int, t_block: int | None = None) -> int:
+    """Padded bars of a band-machine launch over ``T`` bars: a lane
+    multiple (128) that is one block, or a whole number of T blocks
+    (``t_block`` bars each, by default :func:`_t_block`'s)."""
+    T_pad = _round_up(T, 128)
+    if t_block is None and T_pad > _T_BLOCK_MAX:
+        t_block = _T_BLOCK_LONG
+    return T_pad if t_block is None else _round_up(T_pad, t_block)
+
+
+def _t_block(T_pad: int) -> int | None:
+    """Bars per T block of a band-machine launch over ``T_pad`` padded
+    bars: None (one block, the whole history in VMEM) up to
+    ``_T_BLOCK_MAX``, ``_T_BLOCK_LONG`` past it."""
+    return None if T_pad <= _T_BLOCK_MAX else _T_BLOCK_LONG
+
+
 def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
                          t_real, *, T_pad: int, W_pad: int, P_real: int,
                          T_real: int | None, interpret: bool, name: str,
                          lanes_cap: int = 256, aux_rows=(),
-                         scratch_shapes=(), lanes_env: int = 0):
+                         scratch_shapes=(), lanes_env: int = 0,
+                         t_block: int | None = None):
     """Shared launch for every band-machine strategy (Bollinger, RSI, VWAP):
     returns column + ``(N, W_pad, T_pad)`` z-table + one-hot/band/warmup
     lanes into ``_boll_kernel``-shaped cells, :class:`Metrics` out.
+
+    ``t_block`` (bars, dividing ``T_pad``) walks the history in T blocks:
+    a third grid axis, innermost and sequential, over ``(1, t_block, 1)``
+    return and ``(1, W_pad, t_block)`` z-table blocks, with the cell's
+    carry in VMEM scratch (`_TBlock`); the kernel takes ``t_blocked``.
+    None: :func:`_t_block`'s choice, which keeps histories up to
+    ``_T_BLOCK_MAX`` in one block, the program of the one-block cell.
 
     ``lanes_cap`` defaults to 256 — the hysteresis cell's 3-state compose
     ladder keeps ~6 (T_pad, lanes) arrays live, so 512 lanes would press
@@ -1251,6 +1408,14 @@ def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
     is the kernel's name in the device trace, one per strategy."""
     N = close_p.shape[0]
     P_pad = k_lanes.shape[1]
+    if t_block is None:
+        t_block = _t_block(T_pad)
+    if t_block is not None:
+        return _band_machine_t_blocked(
+            kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
+            T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
+            interpret=interpret, name=name, lanes_cap=lanes_cap,
+            lanes_env=lanes_env, t_block=t_block)
     lanes = _widest_lanes(P_pad, lanes_cap, T_pad, lanes_env)
     n_blocks = P_pad // lanes
     table_specs = [] if z_table is None else [
@@ -1289,21 +1454,73 @@ def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
         name=name,
     )(_rets3(close_p), *table_args, *aux_rows, onehot_w, k_lanes, warm,
       *_tr_args(t_real, T_real))
+    return _unpack_metrics(out, N, P_pad, P_real)
+
+
+def _unpack_metrics(out, N: int, P_pad: int, P_real: int) -> Metrics:
+    """``(N, blocks, 16, lanes)`` packed cell rows -> ``(N, P_real)``
+    :class:`Metrics`."""
     return Metrics(*(
         jnp.reshape(out[:, :, k, :], (N, P_pad))[:, :P_real]
         for k in range(9)))
+
+
+def _band_machine_t_blocked(kernel, close_p, z_table, onehot_w, k_lanes,
+                            warm, t_real, *, T_pad: int, W_pad: int,
+                            P_real: int, T_real: int | None,
+                            interpret: bool, name: str, lanes_cap: int,
+                            lanes_env: int, t_block: int):
+    """`_band_machine_pallas` over a ``(N, param blocks, T blocks)`` grid:
+    the HBM z-table and the returns stream in ``t_block``-bar blocks and
+    the cell carries its state across them (`_TBlock`)."""
+    if z_table is None or T_pad % t_block or t_block % 8:
+        raise ValueError(
+            f"a T-blocked band launch takes an HBM z-table and a block of "
+            f"a multiple of 8 bars dividing T_pad={T_pad} (got "
+            f"t_block={t_block}, table={'none' if z_table is None else 'hbm'})")
+    N = close_p.shape[0]
+    P_pad = k_lanes.shape[1]
+    lanes = _widest_lanes(P_pad, lanes_cap, t_block, lanes_env)
+    n_blocks = P_pad // lanes
+    lane_spec = pl.BlockSpec((1, lanes), lambda i, j, t: (0, j),
+                             memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(kernel, t_blocked=True),
+        grid=(N, n_blocks, T_pad // t_block),
+        in_specs=[
+            pl.BlockSpec((1, t_block, 1), lambda i, j, t: (i, t, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, W_pad, t_block), lambda i, j, t: (i, 0, t),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((W_pad, lanes), lambda i, j, t: (0, j),
+                         memory_space=pltpu.VMEM),
+            lane_spec, lane_spec,
+        ] + _tr_specs(T_real),
+        out_specs=pl.BlockSpec(
+            (1, 1, _METRIC_ROWS, lanes), lambda i, j, t: (i, j, 0, 0),
+            memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct(
+            (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((_METRIC_ROWS, lanes), jnp.float32)],
+        interpret=interpret,
+        compiler_params=_T_BLOCKED_PARAMS,
+        name=name,
+    )(_rets3(close_p), z_table, onehot_w, k_lanes, warm,
+      *_tr_args(t_real, T_real))
+    return _unpack_metrics(out, N, P_pad, P_real)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("windows", "T_pad", "W_pad", "P_real", "T_real", "cost",
                      "ppy", "z_exit", "machine", "interpret", "table",
-                     "lanes_env", "epilogue"))
+                     "lanes_env", "epilogue", "t_block"))
 def _fused_boll_call(close, onehot_w, k_lanes, warm, t_real, *, windows: tuple,
                      T_pad: int, W_pad: int, P_real: int, T_real: int | None,
                      cost: float, ppy: int, z_exit: float, interpret: bool,
                      machine: str = "hysteresis", table: str = "inline",
-                     lanes_env: int = 0, epilogue: str = _EPILOGUE_DEFAULT):
+                     lanes_env: int = 0, epilogue: str = _EPILOGUE_DEFAULT,
+                     t_block: int | None = None):
     """Z-score table prep + pallas call in one jit (same dispatch-economy
     rationale as ``_fused_call``).
 
@@ -1317,9 +1534,15 @@ def _fused_boll_call(close, onehot_w, k_lanes, warm, t_real, *, windows: tuple,
     (`_band_kernel_inline`) — the three windowed sums + var/sqrt XLA prep
     and the z-table HBM round-trip measured ~17% (hysteresis) / ~34%
     (touch) of end-to-end wall at headline shapes. ``"hbm"`` keeps the
-    XLA-built table as the A/B twin.
+    XLA-built table as the A/B twin, and serves every T-blocked launch
+    (``t_block``, see `_band_machine_pallas`): the in-kernel build needs
+    the whole history in VMEM.
     """
     N, T = close.shape
+    if t_block is None:
+        t_block = _t_block(T_pad)
+    if t_block is not None:
+        table = "hbm"
     epilogue = _interp_epilogue(epilogue, T_pad, interpret)
     close_p = _pad_last(close, T_pad)
     # The memoryless touch cell has no compose ladder: sign-kernel VMEM
@@ -1362,7 +1585,7 @@ def _fused_boll_call(close, onehot_w, k_lanes, warm, t_real, *, windows: tuple,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
         interpret=interpret, lanes_cap=lanes_cap, lanes_env=lanes_env,
         name=("bollinger_touch_kernel" if machine == "touch"
-              else "bollinger_kernel"))
+              else "bollinger_kernel"), t_block=t_block)
 
 
 def _bollinger_family_sweep(close, window, k, *, machine: str, z_exit: float,
@@ -1370,10 +1593,12 @@ def _bollinger_family_sweep(close, window, k, *, machine: str, z_exit: float,
                             interpret: bool | None,
                             table: str | None = None,
                             epilogue: str | None = None,
-                            carry_out: bool = False) -> Metrics:
+                            carry_out: bool = False,
+                            t_block: int | None = None) -> Metrics:
     """Shared prep for both Bollinger-family wrappers (one z-table/grid
     pipeline, the ``machine`` picks the cell; ``table`` picks the z-table
-    substrate — env ``DBX_BOLL_TABLE`` or ``"inline"``)."""
+    substrate — env ``DBX_BOLL_TABLE`` or ``"inline"``; ``t_block`` bars
+    per T block, see `_band_machine_pallas`)."""
     _check_carry_out_args(carry_out, t_real)
     if carry_out and machine == "hysteresis" and float(z_exit) != 0.0:
         raise ValueError(
@@ -1394,7 +1619,8 @@ def _bollinger_family_sweep(close, window, k, *, machine: str, z_exit: float,
     m = _fused_boll_call(close, onehot_w, k_lanes, warm,
                          _t_real_col(t_real, close),
                          windows=windows,
-                         T_pad=_round_up(T, 128), W_pad=onehot_w.shape[0],
+                         T_pad=band_t_pad(T, t_block),
+                         W_pad=onehot_w.shape[0],
                          P_real=window.shape[0],
                          T_real=T if t_real is None else None,
                          cost=float(cost), ppy=int(periods_per_year),
@@ -1402,7 +1628,8 @@ def _bollinger_family_sweep(close, window, k, *, machine: str, z_exit: float,
                          interpret=bool(interpret),
                          table=_family_table("boll", table),
                          lanes_env=resolve_lanes_cap(),
-                         epilogue=_resolve_epilogue(epilogue))
+                         epilogue=_resolve_epilogue(epilogue),
+                         t_block=t_block)
     if not carry_out:
         return m
     return _carry_out_tail(
@@ -1417,7 +1644,8 @@ def fused_bollinger_touch_sweep(close, window, k, *, t_real=None,
                                 interpret: bool | None = None,
                                 table: str | None = None,
                                 epilogue: str | None = None,
-                                carry_out: bool = False) -> Metrics:
+                                carry_out: bool = False,
+                                t_block: int | None = None) -> Metrics:
     """Fused band-touch sweep: the path-free Bollinger variant.
 
     Same z-table and grid layout as :func:`fused_bollinger_sweep`, but the
@@ -1430,7 +1658,8 @@ def fused_bollinger_touch_sweep(close, window, k, *, t_real=None,
     return _bollinger_family_sweep(
         close, window, k, machine="touch", z_exit=0.0, t_real=t_real,
         cost=cost, periods_per_year=periods_per_year, interpret=interpret,
-        table=table, epilogue=epilogue, carry_out=carry_out)
+        table=table, epilogue=epilogue, carry_out=carry_out,
+        t_block=t_block)
 
 
 def fused_bollinger_sweep(close, window, k, *, t_real=None,
@@ -1439,7 +1668,8 @@ def fused_bollinger_sweep(close, window, k, *, t_real=None,
                           interpret: bool | None = None,
                           table: str | None = None,
                           epilogue: str | None = None,
-                          carry_out: bool = False) -> Metrics:
+                          carry_out: bool = False,
+                          t_block: int | None = None) -> Metrics:
     """Fused Bollinger mean-reversion sweep: ``(N, T)`` closes x ``(P,)`` lanes.
 
     ``window``/``k`` are flat per-combo arrays (:func:`product_grid` order);
@@ -1453,7 +1683,7 @@ def fused_bollinger_sweep(close, window, k, *, t_real=None,
         close, window, k, machine="hysteresis", z_exit=z_exit,
         t_real=t_real, cost=cost, periods_per_year=periods_per_year,
         interpret=interpret, table=table, epilogue=epilogue,
-        carry_out=carry_out)
+        carry_out=carry_out, t_block=t_block)
 
 
 
@@ -2416,11 +2646,12 @@ def fused_donchian_hl_sweep(close, high, low, window, *, t_real=None,
 @functools.partial(
     jax.jit,
     static_argnames=("windows", "T_pad", "W_pad", "P_real", "T_real", "cost",
-                     "ppy", "interpret", "epilogue"))
+                     "ppy", "interpret", "epilogue", "t_block"))
 def _fused_stoch_call(close, high, low, onehot_w, band_lanes, warm, t_real,
                       *, windows: tuple, T_pad: int, W_pad: int, P_real: int,
                       T_real: int | None, cost: float, ppy: int,
-                      interpret: bool, epilogue: str = _EPILOGUE_DEFAULT):
+                      interpret: bool, epilogue: str = _EPILOGUE_DEFAULT,
+                      t_block: int | None = None):
     """%K table prep + the *Bollinger* kernel: the centered stochastic
     oscillator is just another z-score feeding the shared band machine
     (enter beyond ±band, exit at the 50 centerline: z_exit = 0).
@@ -2450,14 +2681,15 @@ def _fused_stoch_call(close, high, low, onehot_w, band_lanes, warm, t_real,
         kernel, close_p, z_table, onehot_w, band_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
         interpret=interpret,
-        name="stochastic_kernel")
+        name="stochastic_kernel", t_block=t_block)
 
 
 def fused_stochastic_sweep(close, high, low, window, band, *, t_real=None,
                            cost: float = 0.0, periods_per_year: int = 252,
                            interpret: bool | None = None,
                            epilogue: str | None = None,
-                           carry_out: bool = False) -> Metrics:
+                           carry_out: bool = False,
+                           t_block: int | None = None) -> Metrics:
     """Fused stochastic-%K reversion sweep: ``(N, T)`` panels x ``(P,)``.
 
     ``window``/``band`` are flat per-combo arrays (:func:`product_grid`
@@ -2482,13 +2714,14 @@ def fused_stochastic_sweep(close, high, low, window, band, *, t_real=None,
         window.astype(np.float32).tobytes(), band.tobytes())
     m = _fused_stoch_call(close, high, low, onehot_w, band_lanes, warm,
                           _t_real_col(t_real, close),
-                          windows=windows, T_pad=_round_up(T, 128),
+                          windows=windows, T_pad=band_t_pad(T, t_block),
                           W_pad=onehot_w.shape[0],
                           P_real=window.shape[0],
                           T_real=T if t_real is None else None,
                           cost=float(cost), ppy=int(periods_per_year),
                           interpret=bool(interpret),
-                          epilogue=_resolve_epilogue(epilogue))
+                          epilogue=_resolve_epilogue(epilogue),
+                          t_block=t_block)
     if not carry_out:
         return m
     return _carry_out_tail(
@@ -2500,12 +2733,13 @@ def fused_stochastic_sweep(close, high, low, window, band, *, t_real=None,
 @functools.partial(
     jax.jit,
     static_argnames=("windows", "T_pad", "W_pad", "P_real", "T_real", "cost",
-                     "ppy", "interpret", "epilogue"))
+                     "ppy", "interpret", "epilogue", "t_block"))
 def _fused_keltner_call(close, high, low, onehot_w, k_lanes, warm, t_real,
                         *, windows: tuple, T_pad: int, W_pad: int,
                         P_real: int, T_real: int | None, cost: float,
                         ppy: int, interpret: bool,
-                        epilogue: str = _EPILOGUE_DEFAULT):
+                        epilogue: str = _EPILOGUE_DEFAULT,
+                        t_block: int | None = None):
     """Keltner z-table prep + the *Bollinger* kernel: the ATR-normalized
     deviation from the EMA midline feeds the shared band machine (enter
     beyond ±k ATRs, exit at the midline re-cross: z_exit = 0).
@@ -2541,14 +2775,15 @@ def _fused_keltner_call(close, high, low, onehot_w, k_lanes, warm, t_real,
         kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
         interpret=interpret,
-        name="keltner_kernel")
+        name="keltner_kernel", t_block=t_block)
 
 
 def fused_keltner_sweep(close, high, low, window, k, *, t_real=None,
                         cost: float = 0.0, periods_per_year: int = 252,
                         interpret: bool | None = None,
                         epilogue: str | None = None,
-                        carry_out: bool = False) -> Metrics:
+                        carry_out: bool = False,
+                        t_block: int | None = None) -> Metrics:
     """Fused Keltner-channel reversion sweep: ``(N, T)`` panels x ``(P,)``.
 
     ``window``/``k`` are flat per-combo arrays (:func:`product_grid`
@@ -2573,13 +2808,14 @@ def fused_keltner_sweep(close, high, low, window, k, *, t_real=None,
         window.astype(np.float32).tobytes(), k.tobytes())
     m = _fused_keltner_call(close, high, low, onehot_w, k_lanes, warm,
                             _t_real_col(t_real, close),
-                            windows=windows, T_pad=_round_up(T, 128),
+                            windows=windows, T_pad=band_t_pad(T, t_block),
                             W_pad=onehot_w.shape[0],
                             P_real=window.shape[0],
                             T_real=T if t_real is None else None,
                             cost=float(cost), ppy=int(periods_per_year),
                             interpret=bool(interpret),
-                            epilogue=_resolve_epilogue(epilogue))
+                            epilogue=_resolve_epilogue(epilogue),
+                            t_block=t_block)
     if not carry_out:
         return m
     return _carry_out_tail(
@@ -2608,11 +2844,12 @@ def _single_window_grid_setup(vals_bytes: bytes, warm_offset: float,
 @functools.partial(
     jax.jit,
     static_argnames=("windows", "T_pad", "W_pad", "P_real", "T_real", "cost",
-                     "ppy", "interpret", "epilogue"))
+                     "ppy", "interpret", "epilogue", "t_block"))
 def _fused_rsi_call(close, onehot_p, band_lanes, warm, t_real, *,
                     windows: tuple, T_pad: int, W_pad: int, P_real: int,
                     T_real: int | None, cost: float, ppy: int,
-                    interpret: bool, epilogue: str = _EPILOGUE_DEFAULT):
+                    interpret: bool, epilogue: str = _EPILOGUE_DEFAULT,
+                    t_block: int | None = None):
     """RSI table prep + the *Bollinger* kernel: ``rsi - 50`` is just another
     z-score feeding the shared band machine (enter beyond ±band, exit at the
     centerline), so the whole kernel is reused verbatim with z_exit=0.
@@ -2645,14 +2882,15 @@ def _fused_rsi_call(close, onehot_p, band_lanes, warm, t_real, *,
         kernel, close_p, z_tbl, onehot_p, band_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
         interpret=interpret,
-        name="rsi_kernel")
+        name="rsi_kernel", t_block=t_block)
 
 
 def fused_rsi_sweep(close, period, band, *, t_real=None, cost: float = 0.0,
                     periods_per_year: int = 252,
                     interpret: bool | None = None,
                     epilogue: str | None = None,
-                    carry_out: bool = False) -> Metrics:
+                    carry_out: bool = False,
+                    t_block: int | None = None) -> Metrics:
     """Fused RSI mean-reversion sweep: ``(N, T)`` closes x ``(P,)`` lanes.
 
     ``period``/``band`` are flat per-combo arrays (:func:`product_grid`
@@ -2670,12 +2908,13 @@ def fused_rsi_sweep(close, period, band, *, t_real=None, cost: float = 0.0,
         period.astype(np.float32).tobytes(), band.tobytes())
     m = _fused_rsi_call(close, onehot_p, band_lanes, warm,
                         _t_real_col(t_real, close),
-                        windows=windows, T_pad=_round_up(T, 128),
+                        windows=windows, T_pad=band_t_pad(T, t_block),
                         W_pad=onehot_p.shape[0], P_real=period.shape[0],
                         T_real=T if t_real is None else None,
                         cost=float(cost), ppy=int(periods_per_year),
                         interpret=bool(interpret),
-                        epilogue=_resolve_epilogue(epilogue))
+                        epilogue=_resolve_epilogue(epilogue),
+                        t_block=t_block)
     if not carry_out:
         return m
     return _carry_out_tail(m, "rsi", {"close": close},
@@ -3226,11 +3465,12 @@ def _trix_grid_setup(span_bytes: bytes, signal_bytes: bytes):
 @functools.partial(
     jax.jit,
     static_argnames=("windows", "T_pad", "W_pad", "P_real", "T_real", "cost",
-                     "ppy", "interpret", "epilogue"))
+                     "ppy", "interpret", "epilogue", "t_block"))
 def _fused_vwap_call(close, volume, onehot_w, k_lanes, warm, t_real, *,
                      windows: tuple, T_pad: int, W_pad: int, P_real: int,
                      T_real: int | None, cost: float, ppy: int,
-                     interpret: bool, epilogue: str = _EPILOGUE_DEFAULT):
+                     interpret: bool, epilogue: str = _EPILOGUE_DEFAULT,
+                     t_block: int | None = None):
     """VWAP-deviation z-table prep + the *Bollinger* kernel.
 
     ``models.vwap`` vectorized over the distinct-window axis: rolling VWAP =
@@ -3239,12 +3479,11 @@ def _fused_vwap_call(close, volume, onehot_w, k_lanes, warm, t_real, *,
     shared band machine (enter beyond ±k, exit when price re-crosses the
     anchor: z_exit = 0). The first fused kernel consuming the volume column.
 
-    Replicates the generic float op order on the real-bar region (cumsum-
-    difference rolling sums, uncentered rolling-mean numerator, series-
-    centered second moments, eps = 1e-12). Warmup rows — where the generic
-    path's NaN-filled window sums make ``v > eps`` False and the deviation
-    falls back to exactly 0 — are forced to 0 explicitly, as is the
-    zero-volume-window fallback.
+    Replicates the generic float op order on the real-bar region (the
+    deviation in double-f32 from compensated window-sum pairs,
+    ``models.vwap.vwap_deviation``; uncentered rolling-mean numerator,
+    series-centered second moments, eps = 1e-12). Warmup rows and
+    zero-volume windows hold a deviation of exactly 0, as there.
     """
     T = close.shape[1]
     epilogue = _interp_epilogue(epilogue, T_pad, interpret)
@@ -3253,10 +3492,12 @@ def _fused_vwap_call(close, volume, onehot_w, k_lanes, warm, t_real, *,
     w_col, w_f, t_row, windowed_sum, windowed_sum3 = _cumsum_window_tools(
         windows, T_pad)
 
-    pv = windowed_sum(close_p * vol_p)
-    v = windowed_sum(vol_p)
-    have = (t_row >= (w_col - 1))[None] & (v > _EPS)
-    dev = jnp.where(have, close_p[:, None, :] - pv / (v + _EPS), 0.0)
+    pv = windowed_sum(None, prefix=rolling.prefix_prod2(close_p, vol_p),
+                      as_pair=True)
+    v = windowed_sum(vol_p, as_pair=True)
+    dev = jnp.where((t_row >= (w_col - 1))[None],
+                    rolling.vwap_deviation2(close_p[:, None, :], pv, v,
+                                            _EPS), 0.0)
 
     m = windowed_sum3(dev) / w_f
     # Center with the deviation's mean over the REAL bars (rolling.py's
@@ -3276,14 +3517,15 @@ def _fused_vwap_call(close, volume, onehot_w, k_lanes, warm, t_real, *,
         kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
         T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
         interpret=interpret,
-        name="vwap_kernel")
+        name="vwap_kernel", t_block=t_block)
 
 
 def fused_vwap_sweep(close, volume, window, k, *, t_real=None,
                      cost: float = 0.0, periods_per_year: int = 252,
                      interpret: bool | None = None,
                      epilogue: str | None = None,
-                     carry_out: bool = False) -> Metrics:
+                     carry_out: bool = False,
+                     t_block: int | None = None) -> Metrics:
     """Fused VWAP-deviation reversion sweep: ``(N, T)`` panels x ``(P,)``.
 
     ``window``/``k`` are flat per-combo arrays (:func:`product_grid` order);
@@ -3308,11 +3550,12 @@ def fused_vwap_sweep(close, volume, window, k, *, t_real=None,
     m = _fused_vwap_call(close, volume, onehot_w, k_lanes, warm,
                          _t_real_col(t_real, close),
                          windows=windows,
-                         T_pad=_round_up(T, 128), W_pad=onehot_w.shape[0],
+                         T_pad=band_t_pad(T, t_block), W_pad=onehot_w.shape[0],
                          P_real=P, T_real=T if t_real is None else None,
                          cost=float(cost), ppy=int(periods_per_year),
                          interpret=bool(interpret),
-                         epilogue=_resolve_epilogue(epilogue))
+                         epilogue=_resolve_epilogue(epilogue),
+                         t_block=t_block)
     if not carry_out:
         return m
     return _carry_out_tail(m, "vwap_reversion",

@@ -36,6 +36,22 @@ def cache_max_bytes() -> int:
                                     _DEFAULT_CACHE_MB)) * 1024 * 1024)
 
 
+def host_cache_max_bytes() -> int:
+    """The host level's budget: ``DBX_PANEL_CACHE_MB`` where set; else
+    enough for every panel whose pages the device page pool holds — the
+    pool's bound times the five OHLCV fields a decoded panel keeps (a
+    one-field family's pages are a fifth of its panel) — and never under
+    the default. A universe resident on the device is then resident on
+    the host too, and no digest-only job of it goes back to the
+    dispatcher for its bytes (``FetchPayload``)."""
+    if os.environ.get("DBX_PANEL_CACHE_MB") is not None:
+        return cache_max_bytes()
+    from .page_pool import pool_max_bytes
+
+    return max(cache_max_bytes(),
+               len(data_mod.OHLCV._fields) * pool_max_bytes())
+
+
 class PanelCache:
     """Two-level digest-keyed panel cache (dispatch by digest, worker side).
 
@@ -54,9 +70,11 @@ class PanelCache:
       overlapping histories share pages across digests, where the block
       level would duplicate the whole ``(5, T)`` history per digest.
 
-    The first two levels are LRU-bounded by approximate bytes
-    (``DBX_PANEL_CACHE_MB``, default 256 per level); the page pool by
-    ``DBX_PAGE_POOL_MB``. Eviction is not an error: the worker recovers
+    The first two levels are LRU-bounded by approximate bytes: the device
+    level by ``DBX_PANEL_CACHE_MB`` (default 256), the host level by the
+    same knob where it is set, else by what the page pool holds
+    (:func:`host_cache_max_bytes`); the page pool by ``DBX_PAGE_POOL_MB``
+    or a share of the device's memory (``page_pool.pool_max_bytes``). Eviction is not an error: the worker recovers
     a digest-only miss through the dispatcher's ``FetchPayload`` RPC, and
     a pool-rejected group falls back to the dense stack path.
     Thread-safe — the worker's control thread probes/fills the host level
@@ -69,13 +87,15 @@ class PanelCache:
 
         self.max_bytes = (cache_max_bytes() if max_bytes is None
                           else int(max_bytes))
+        self.host_max_bytes = (host_cache_max_bytes() if max_bytes is None
+                               else int(max_bytes))
         self._lock = threading.Lock()
         self._pages = None
         # Both levels ride the ONE eviction/accounting implementation the
         # dispatcher's blob store uses (panel_store.ByteLRU); only the
         # pricing differs (decoded array nbytes vs caller-supplied device
         # block size).
-        self._series = ByteLRU(self.max_bytes, self._nbytes)
+        self._series = ByteLRU(self.host_max_bytes, self._nbytes)
         self._device = ByteLRU(self.max_bytes)   # put() passes nbytes
         reg = registry or obs.get_registry()
         self._reg = reg
@@ -158,7 +178,8 @@ class PanelCache:
                    "host_bytes": self._series.bytes,
                    "device_panels": len(self._device),
                    "device_bytes": self._device.bytes,
-                   "max_bytes": self.max_bytes}
+                   "max_bytes": self.max_bytes,
+                   "host_max_bytes": self.host_max_bytes}
             pages = self._pages
         if pages is not None:
             out["page_pool"] = pages.stats()
@@ -426,6 +447,14 @@ class JaxSweepBackend:
             help="result bytes copied device->host")
         self._c_backtests = reg.counter(
             "dbx_backtests_total", help="(ticker x param) combos computed")
+        self._c_route = {
+            route: reg.counter(
+                "dbx_route_jobs_total",
+                help="sweep jobs run, by route (fused = a fused kernel "
+                     "over dense stacks, paged = a fused kernel over the "
+                     "page pool, timeshard = bars sharded over the chips, "
+                     "generic = the unfused XLA sweep)", route=route)
+            for route in ("fused", "paged", "timeshard", "generic")}
         self._h_jit = {
             phase: reg.histogram(
                 "dbx_jit_call_seconds",
@@ -654,7 +683,9 @@ class JaxSweepBackend:
 
     # The longest history and the most table windows routed to a fused
     # kernel: every family compiles there for the v5e under ops.fused's
-    # scoped-VMEM limit (tests/test_tpu_compile.py holds the cap).
+    # scoped-VMEM limit (tests/test_tpu_compile.py holds the cap). The
+    # band-machine families walk longer histories in T blocks and take
+    # no bar cap (`bar_capped`).
     _FUSED_MAX_BARS = 8192
     _FUSED_MAX_WINDOWS = 128
     # Families whose rolling variance (``s2 - s1**2 / w`` on series-
@@ -909,6 +940,7 @@ class JaxSweepBackend:
         job0 = group[0]
         fam = self._TIMESHARD_STRATEGIES[job0.strategy]
         fn = getattr(timeshard, fam.fn_name)
+        self._c_route["timeshard"].inc(len(group))
         tmesh = self._time_mesh()
         n_dev = tmesh.devices.size
         cost = float(job0.cost)
@@ -1005,7 +1037,7 @@ class JaxSweepBackend:
                 return (f"max window {int(wins.max())} exceeds the channel "
                         f"view bound {bound}")
         t_max = int(max(lengths))
-        if t_max > cls._FUSED_MAX_BARS:
+        if bar_capped(job.strategy, t_max, cls._FUSED_MAX_BARS):
             return (f"{t_max} bars exceed the kernel VMEM cap of "
                     f"{cls._FUSED_MAX_BARS}")
         return None
@@ -1202,7 +1234,7 @@ class JaxSweepBackend:
         is emitted only when real work happened).
         """
         cache = self.panel_cache
-        if cache.max_bytes <= 0:
+        if cache.host_max_bytes <= 0:
             return 0
         warmed = 0
         decoded: dict = {}
@@ -1236,7 +1268,9 @@ class JaxSweepBackend:
             # path will demote to dense would waste H2D and evict pages
             # live groups are about to gather.
             s = decoded.get(job.panel_digest)
-            if (s is not None and s.n_bars <= self._FUSED_MAX_BARS
+            if (s is not None
+                    and not bar_capped(job.strategy, s.n_bars,
+                                       self._FUSED_MAX_BARS)
                     and self._fused_ops.paged_supported(job.strategy)):
                 try:
                     grid = wire.grid_from_proto(job.grid)
@@ -1471,6 +1505,7 @@ class JaxSweepBackend:
             return self._submit_scenario_materialized(job, specs,
                                                       series, t0)
         self._c_scenario_route["fused"].inc(len(specs))
+        self._c_route["fused"].inc(len(specs))
         P = sweep_mod.grid_size(grid) if grid else 1
         self._observe_submit(
             job.strategy, "scenario", t0,
@@ -1703,6 +1738,7 @@ class JaxSweepBackend:
                                      group=group)
                 continue
             if group[0].best_returns:
+                self._c_route["generic"].inc(len(group))
                 pending.append(self._submit_best_returns_group(
                     group, series, lengths, t0))
                 self._observe_submit(group[0].strategy, "best_returns", t0,
@@ -1804,7 +1840,8 @@ class JaxSweepBackend:
                 continue
             if (self.use_paged and demotion is not None
                     and group[0].strategy in self._FUSED_STRATEGIES
-                    and t_max_g > self._FUSED_MAX_BARS):
+                    and bar_capped(group[0].strategy, t_max_g,
+                                   self._FUSED_MAX_BARS)):
                 # Over-cap ragged groups route through paging FIRST: the
                 # paged group key no longer buckets by length, so one
                 # oversized panel would otherwise demote every under-cap
@@ -1812,7 +1849,8 @@ class JaxSweepBackend:
                 # the under-cap subset keeps the fused (paged) route,
                 # only the genuinely-long remainder stays demoted.
                 ok_idx = [i for i, t in enumerate(lengths)
-                          if int(t) <= self._FUSED_MAX_BARS]
+                          if not bar_capped(group[0].strategy, int(t),
+                                            self._FUSED_MAX_BARS)]
                 if ok_idx and len(ok_idx) < len(group) \
                         and self._fused_demotion_reason(
                             group[0], axes,
@@ -1885,6 +1923,7 @@ class JaxSweepBackend:
                 else:
                     m = sweep_mod.jit_sweep(panel, strategy, grid,
                                             **kwargs)
+            self._c_route["generic"].inc(len(group))
             route = ("generic"
                      + ("_mesh" if self._mesh is not None else ""))
             # Shape in the cold key: jit compiles per (rows, bars), so a
@@ -2115,6 +2154,7 @@ class JaxSweepBackend:
                     runner, arrays, t_real)
             else:
                 m = spec.run(*arrays, grid, cost, ppy, t_real)
+        self._c_route["paged" if paged else "fused"].inc(len(group))
         # paged implies mesh is None, so the suffix is vacuous there.
         route = (("paged" if paged else "fused")
                  + ("_mesh" if self._mesh is not None else ""))
@@ -2297,6 +2337,7 @@ class JaxSweepBackend:
                     self._WF_FUSED_MIN_COMBOS
                     and self._fused_demotion_reason(
                         job0, axes, [job0.wf_train]) is None)
+        self._c_route["fused" if fused_wf else "generic"].inc(len(good))
         if fused_wf:
             spec = self._FUSED_STRATEGIES[job0.strategy]
             prod_np = {k: np.asarray(v)
@@ -2453,6 +2494,7 @@ class JaxSweepBackend:
         x_close = _stack_field_ragged(xs, t_max)
         uniform = len(set(int(v) for v in lens)) == 1
         if wf:
+            self._c_route["generic"].inc(len(group))
             # Walk-forward pairs (JobSpec.wf_* + strategy "pairs"): one
             # stitched OOS metrics row per job, like the single-asset path.
             # Window starts are global bar indices, so ragged groups refit
@@ -2503,7 +2545,7 @@ class JaxSweepBackend:
         elif n_lb > self._FUSED_MAX_WINDOWS:
             demotion = (f"{n_lb} distinct lookbacks exceed the kernel cap "
                         f"of {self._FUSED_MAX_WINDOWS}")
-        elif t_max > self._FUSED_MAX_BARS:
+        elif bar_capped("pairs", t_max, self._FUSED_MAX_BARS):
             demotion = (f"{t_max} bars exceed the kernel VMEM cap of "
                         f"{self._FUSED_MAX_BARS}")
         if ((not self.use_fused or demotion is not None)
@@ -2533,6 +2575,8 @@ class JaxSweepBackend:
         if self.use_fused and demotion is not None:
             log.warning("jobs %s (pairs) demoted to the generic path: %s",
                         [j.id for j in group], demotion)
+        self._c_route["fused" if self.use_fused and demotion is None
+                      else "generic"].inc(len(group))
         if self.use_fused and demotion is None:
             from ..ops import fused
 
@@ -2594,6 +2638,7 @@ class JaxSweepBackend:
         from ..parallel import timeshard
 
         job0 = group[0]
+        self._c_route["timeshard"].inc(len(group))
         tmesh = self._time_mesh()
         n_dev = tmesh.devices.size
         T_pad = -(-t // n_dev) * n_dev
@@ -2717,6 +2762,20 @@ class JaxSweepBackend:
 
     def process(self, jobs) -> list[Completion]:
         return self.collect(self.submit(jobs))
+
+
+def bar_capped(strategy: str, bars: int, cap: int) -> bool:
+    """The router's one bar-cap test: True when a ``bars``-bar history is
+    longer than the fused kernel of ``strategy`` takes on one chip —
+    ``cap`` bars (``JaxSweepBackend._FUSED_MAX_BARS``, the longest
+    history every kernel holds whole in VMEM), unless the kernel walks
+    the history in T blocks (``ops.fused.T_BLOCKED_FAMILIES``) and takes
+    any length."""
+    if bars <= cap:
+        return False
+    from ..ops import fused
+
+    return strategy not in fused.T_BLOCKED_FAMILIES
 
 
 def _timeshard_window_reason(wins, n_combos: int, t_min: int, n_dev: int, *,

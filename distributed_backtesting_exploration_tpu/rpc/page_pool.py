@@ -25,9 +25,11 @@ special-cased:
   different dates, scenario twins sharing a base) share every aligned
   identical page, so device bytes grow sublinearly in ticker count.
 
-Bounded by ``DBX_PAGE_POOL_MB`` (default 64) with LRU slot reuse; the
-pool array grows geometrically up to the bound, so idle workers do not
-pin the full budget. Uploads batch all of a group's missing pages into
+Bounded by ``DBX_PAGE_POOL_MB`` where it is set, else by an eighth of
+the device's memory (its ``memory_stats()`` limit; 64 MB on a device
+that reports none), with LRU slot reuse; the pool array grows
+geometrically up to the bound, so idle workers do not pin the full
+budget. Uploads batch all of a group's missing pages into
 one donated scatter (in-place on backends with buffer donation), and a
 group whose working set cannot fit — or whose pages would evict each
 other mid-assembly — is REJECTED (``prepare`` returns None) so the
@@ -60,15 +62,37 @@ from .. import obs
 from ..ops.fused import resolve_page_bars
 
 _DEFAULT_POOL_MB = 64
+# The share of the device's memory the pool may take where
+# DBX_PAGE_POOL_MB is unset: an eighth, beside the third one launch may
+# take (rpc.compute.JaxSweepBackend._HBM_BUDGET) and the results in
+# flight. A universe of long histories then stays resident (500 x
+# 98,280 minute bars of close and volume are 393 MB of pages; the v5e's
+# eighth is ~2 GB) instead of re-uploading every job's pages.
+_DEVICE_SHARE = 8
 _MIN_SLOTS = 8              # smallest useful pool (growth floor)
 _PANEL_MEMO_CAP = 16384     # (digest, field) -> page-key lists retained
 
 
 def pool_max_bytes() -> int:
     """Pool byte bound, read lazily (import-time env capture would pin
-    the knob before tests/operators can set it)."""
-    return int(float(os.environ.get("DBX_PAGE_POOL_MB",
-                                    _DEFAULT_POOL_MB)) * 1024 * 1024)
+    the knob before tests/operators can set it): ``DBX_PAGE_POOL_MB``
+    where set, else ``1 / _DEVICE_SHARE`` of the first local device's
+    memory limit, and never under the 64 MB default."""
+    raw = os.environ.get("DBX_PAGE_POOL_MB")
+    if raw is not None:
+        return int(float(raw) * 1024 * 1024)
+    return max(_DEFAULT_POOL_MB * 1024 * 1024,
+               device_memory_limit() // _DEVICE_SHARE)
+
+
+def device_memory_limit() -> int:
+    """Bytes the first local device lets a process hold
+    (``memory_stats()["bytes_limit"]``), or 0 where it reports none (the
+    CPU)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 0))
 
 
 def page_key(page_bytes: bytes) -> str:

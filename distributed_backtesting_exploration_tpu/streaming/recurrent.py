@@ -393,7 +393,7 @@ def _head_vwap(win, D, grid, state, pos0):
     K = win["close"].shape[-1] - D
 
     def fn(c, v, p):
-        dev = c - vwap_mod.rolling_vwap(c, v, p["window"])
+        dev = vwap_mod.vwap_deviation(c, v, p["window"])
         return rolling.rolling_zscore(dev, p["window"], fill=0.0)
 
     z = _per_lane(fn, [win["close"], win["volume"]], grid)[..., K:]

@@ -117,22 +117,38 @@ def test_router_bar_cap(one_chip):
         (8, bars), sharding=one_chip)
 
 
-@pytest.mark.parametrize("strategy", ["pairs", "vwap_reversion"])
-def test_router_group_at_the_bar_cap_fits_hbm(one_chip, strategy):
+# A year of minute bars (390 x 252) on the sp500_minute_vwap cell's grid:
+# 39 VWAP windows x 32 bands, through the T-blocked band machine.
+MINUTE_BARS = 98280
+MINUTE_AXES = {"window": np.arange(10, 400, 10, dtype=np.float32),
+               "k": np.arange(0.5, 4.5, 0.125, dtype=np.float32)}
+
+
+@pytest.mark.parametrize("strategy,bars", [
+    pytest.param("pairs", None, id="pairs"),
+    pytest.param("vwap_reversion", None, id="vwap_reversion"),
+    pytest.param("vwap_reversion", MINUTE_BARS, id="vwap_reversion-minute"),
+])
+def test_router_group_at_the_bar_cap_fits_hbm(one_chip, strategy, bars):
     """The largest group the router forms at its bar cap, for the two
     families that take the most device memory there (their 3-D z-table
     preps: pairs ~2.8 KB per ticker and bar at 128 windows, VWAP ~2.3
     KB): the compiled program fits the HBM budget the router sizes
-    groups by."""
+    groups by. The minute case is a year of minute bars on the
+    sp500_minute_vwap grid, past the cap: the T-blocked ``vwap_kernel``
+    compiles there, and the router's group fits."""
     from distributed_backtesting_exploration_tpu.rpc import (
         backtesting_pb2 as pb, wire)
 
-    bars = JaxSweepBackend._FUSED_MAX_BARS
     window = "lookback" if strategy == "pairs" else "window"
     k = "z_entry" if strategy == "pairs" else "k"
-    axes = {window: np.arange(JaxSweepBackend._FUSED_MAX_WINDOWS,
-                              dtype=np.float32) * 2 + 10,
-            k: np.linspace(0.5, 3.0, 8).astype(np.float32)}
+    if bars is None:
+        bars = JaxSweepBackend._FUSED_MAX_BARS
+        axes = {window: np.arange(JaxSweepBackend._FUSED_MAX_WINDOWS,
+                                  dtype=np.float32) * 2 + 10,
+                k: np.linspace(0.5, 3.0, 8).astype(np.float32)}
+    else:
+        axes = MINUTE_AXES
     legs = 2 if strategy == "pairs" else 0
     job = pb.JobSpec(strategy=strategy, panel_bytes_len=8 + 20 * bars,
                      panel_bytes_len2=legs and 8 + 20 * bars,
