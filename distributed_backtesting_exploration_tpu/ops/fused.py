@@ -1362,7 +1362,7 @@ T_BLOCKED_FAMILIES = frozenset({"bollinger", "bollinger_touch", "rsi",
                                 "stochastic", "keltner", "vwap_reversion"})
 
 
-def band_t_pad(T: int, t_block: int | None = None) -> int:
+def _band_t_pad(T: int, t_block: int | None = None) -> int:
     """Padded bars of a band-machine launch over ``T`` bars: a lane
     multiple (128) that is one block, or a whole number of T blocks
     (``t_block`` bars each, by default :func:`_t_block`'s)."""
@@ -1410,104 +1410,58 @@ def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
     P_pad = k_lanes.shape[1]
     if t_block is None:
         t_block = _t_block(T_pad)
-    if t_block is not None:
-        return _band_machine_t_blocked(
-            kernel, close_p, z_table, onehot_w, k_lanes, warm, t_real,
-            T_pad=T_pad, W_pad=W_pad, P_real=P_real, T_real=T_real,
-            interpret=interpret, name=name, lanes_cap=lanes_cap,
-            lanes_env=lanes_env, t_block=t_block)
-    lanes = _widest_lanes(P_pad, lanes_cap, T_pad, lanes_env)
-    n_blocks = P_pad // lanes
-    table_specs = [] if z_table is None else [
-        pl.BlockSpec((1, W_pad, T_pad), lambda i, j: (i, 0, 0),
-                     memory_space=pltpu.VMEM)]
-    table_args = [] if z_table is None else [z_table]
-    aux_rows = [row if row.ndim == 3 else row[:, None, :]
-                for row in aux_rows]
-    aux_specs = [
-        pl.BlockSpec((1, row.shape[1], T_pad), lambda i, j: (i, 0, 0),
-                     memory_space=pltpu.VMEM)
-        for row in aux_rows
-    ]
-    out = pl.pallas_call(
-        kernel,
-        grid=(N, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, T_pad, 1), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ] + table_specs + aux_specs + [
-            pl.BlockSpec((W_pad, lanes), lambda i, j: (0, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, lanes), lambda i, j: (0, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, lanes), lambda i, j: (0, j),
-                         memory_space=pltpu.VMEM),
-        ] + _tr_specs(T_real),
-        out_specs=pl.BlockSpec(
-            (1, 1, _METRIC_ROWS, lanes), lambda i, j: (i, j, 0, 0),
-            memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(
-            (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
-        scratch_shapes=list(scratch_shapes),
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-        name=name,
-    )(_rets3(close_p), *table_args, *aux_rows, onehot_w, k_lanes, warm,
-      *_tr_args(t_real, T_real))
-    return _unpack_metrics(out, N, P_pad, P_real)
-
-
-def _unpack_metrics(out, N: int, P_pad: int, P_real: int) -> Metrics:
-    """``(N, blocks, 16, lanes)`` packed cell rows -> ``(N, P_real)``
-    :class:`Metrics`."""
-    return Metrics(*(
-        jnp.reshape(out[:, :, k, :], (N, P_pad))[:, :P_real]
-        for k in range(9)))
-
-
-def _band_machine_t_blocked(kernel, close_p, z_table, onehot_w, k_lanes,
-                            warm, t_real, *, T_pad: int, W_pad: int,
-                            P_real: int, T_real: int | None,
-                            interpret: bool, name: str, lanes_cap: int,
-                            lanes_env: int, t_block: int):
-    """`_band_machine_pallas` over a ``(N, param blocks, T blocks)`` grid:
-    the HBM z-table and the returns stream in ``t_block``-bar blocks and
-    the cell carries its state across them (`_TBlock`)."""
-    if z_table is None or T_pad % t_block or t_block % 8:
+    blocked = t_block is not None
+    if blocked and (z_table is None or T_pad % t_block or t_block % 8):
         raise ValueError(
             f"a T-blocked band launch takes an HBM z-table and a block of "
             f"a multiple of 8 bars dividing T_pad={T_pad} (got "
             f"t_block={t_block}, table={'none' if z_table is None else 'hbm'})")
-    N = close_p.shape[0]
-    P_pad = k_lanes.shape[1]
-    lanes = _widest_lanes(P_pad, lanes_cap, t_block, lanes_env)
+    T_blk = t_block if blocked else T_pad
+    lanes = _widest_lanes(P_pad, lanes_cap, T_blk, lanes_env)
     n_blocks = P_pad // lanes
-    lane_spec = pl.BlockSpec((1, lanes), lambda i, j, t: (0, j),
-                             memory_space=pltpu.VMEM)
+    if blocked:
+        grid = (N, n_blocks, T_pad // T_blk)
+        kernel = functools.partial(kernel, t_blocked=True)
+        scratch_shapes = [pltpu.VMEM((_METRIC_ROWS, lanes), jnp.float32)]
+
+        def spec(shape, index):
+            return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+    else:
+        grid = (N, n_blocks)
+
+        def spec(shape, index):
+            return pl.BlockSpec(shape, lambda i, j: index(i, j, 0),
+                                memory_space=pltpu.VMEM)
+    table_specs = [] if z_table is None else [
+        spec((1, W_pad, T_blk), lambda i, j, t: (i, 0, t))]
+    table_args = [] if z_table is None else [z_table]
+    aux_rows = [row if row.ndim == 3 else row[:, None, :]
+                for row in aux_rows]
+    aux_specs = [spec((1, row.shape[1], T_pad), lambda i, j, t: (i, 0, 0))
+                 for row in aux_rows]
     out = pl.pallas_call(
-        functools.partial(kernel, t_blocked=True),
-        grid=(N, n_blocks, T_pad // t_block),
+        kernel,
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, t_block, 1), lambda i, j, t: (i, t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, W_pad, t_block), lambda i, j, t: (i, 0, t),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((W_pad, lanes), lambda i, j, t: (0, j),
-                         memory_space=pltpu.VMEM),
-            lane_spec, lane_spec,
+            spec((1, T_blk, 1), lambda i, j, t: (i, t, 0)),
+        ] + table_specs + aux_specs + [
+            spec((W_pad, lanes), lambda i, j, t: (0, j)),
+            spec((1, lanes), lambda i, j, t: (0, j)),
+            spec((1, lanes), lambda i, j, t: (0, j)),
         ] + _tr_specs(T_real),
-        out_specs=pl.BlockSpec(
-            (1, 1, _METRIC_ROWS, lanes), lambda i, j, t: (i, j, 0, 0),
-            memory_space=pltpu.VMEM),
+        out_specs=spec((1, 1, _METRIC_ROWS, lanes),
+                       lambda i, j, t: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (N, n_blocks, _METRIC_ROWS, lanes), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((_METRIC_ROWS, lanes), jnp.float32)],
+        scratch_shapes=list(scratch_shapes),
         interpret=interpret,
-        compiler_params=_T_BLOCKED_PARAMS,
+        compiler_params=_T_BLOCKED_PARAMS if blocked else _COMPILER_PARAMS,
         name=name,
-    )(_rets3(close_p), z_table, onehot_w, k_lanes, warm,
+    )(_rets3(close_p), *table_args, *aux_rows, onehot_w, k_lanes, warm,
       *_tr_args(t_real, T_real))
-    return _unpack_metrics(out, N, P_pad, P_real)
+    return Metrics(*(
+        jnp.reshape(out[:, :, k, :], (N, P_pad))[:, :P_real]
+        for k in range(9)))
 
 
 @functools.partial(
@@ -1619,7 +1573,7 @@ def _bollinger_family_sweep(close, window, k, *, machine: str, z_exit: float,
     m = _fused_boll_call(close, onehot_w, k_lanes, warm,
                          _t_real_col(t_real, close),
                          windows=windows,
-                         T_pad=band_t_pad(T, t_block),
+                         T_pad=_band_t_pad(T, t_block),
                          W_pad=onehot_w.shape[0],
                          P_real=window.shape[0],
                          T_real=T if t_real is None else None,
@@ -2714,7 +2668,7 @@ def fused_stochastic_sweep(close, high, low, window, band, *, t_real=None,
         window.astype(np.float32).tobytes(), band.tobytes())
     m = _fused_stoch_call(close, high, low, onehot_w, band_lanes, warm,
                           _t_real_col(t_real, close),
-                          windows=windows, T_pad=band_t_pad(T, t_block),
+                          windows=windows, T_pad=_band_t_pad(T, t_block),
                           W_pad=onehot_w.shape[0],
                           P_real=window.shape[0],
                           T_real=T if t_real is None else None,
@@ -2808,7 +2762,7 @@ def fused_keltner_sweep(close, high, low, window, k, *, t_real=None,
         window.astype(np.float32).tobytes(), k.tobytes())
     m = _fused_keltner_call(close, high, low, onehot_w, k_lanes, warm,
                             _t_real_col(t_real, close),
-                            windows=windows, T_pad=band_t_pad(T, t_block),
+                            windows=windows, T_pad=_band_t_pad(T, t_block),
                             W_pad=onehot_w.shape[0],
                             P_real=window.shape[0],
                             T_real=T if t_real is None else None,
@@ -2908,7 +2862,7 @@ def fused_rsi_sweep(close, period, band, *, t_real=None, cost: float = 0.0,
         period.astype(np.float32).tobytes(), band.tobytes())
     m = _fused_rsi_call(close, onehot_p, band_lanes, warm,
                         _t_real_col(t_real, close),
-                        windows=windows, T_pad=band_t_pad(T, t_block),
+                        windows=windows, T_pad=_band_t_pad(T, t_block),
                         W_pad=onehot_p.shape[0], P_real=period.shape[0],
                         T_real=T if t_real is None else None,
                         cost=float(cost), ppy=int(periods_per_year),
@@ -3550,7 +3504,7 @@ def fused_vwap_sweep(close, volume, window, k, *, t_real=None,
     m = _fused_vwap_call(close, volume, onehot_w, k_lanes, warm,
                          _t_real_col(t_real, close),
                          windows=windows,
-                         T_pad=band_t_pad(T, t_block), W_pad=onehot_w.shape[0],
+                         T_pad=_band_t_pad(T, t_block), W_pad=onehot_w.shape[0],
                          P_real=P, T_real=T if t_real is None else None,
                          cost=float(cost), ppy=int(periods_per_year),
                          interpret=bool(interpret),

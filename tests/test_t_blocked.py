@@ -103,12 +103,12 @@ def test_single_t_block_is_bit_identical_to_the_unblocked_cell(family):
 
 
 def test_band_t_pad_and_block():
-    assert fused.band_t_pad(1260) == 1280
-    assert fused.band_t_pad(8192) == 8192
+    assert fused._band_t_pad(1260) == 1280
+    assert fused._band_t_pad(8192) == 8192
     assert fused._t_block(8192) is None
-    assert fused.band_t_pad(98280) == 98304
+    assert fused._band_t_pad(98280) == 98304
     assert fused._t_block(98304) == 2048
-    assert fused.band_t_pad(434, 128) == 512
+    assert fused._band_t_pad(434, 128) == 512
 
 
 def test_t_blocked_launch_needs_a_dividing_block():
