@@ -1415,7 +1415,7 @@ def _band_machine_pallas(kernel, close_p, z_table, onehot_w, k_lanes, warm,
         raise ValueError(
             f"a T-blocked band launch takes an HBM z-table and a block of "
             f"a multiple of 8 bars dividing T_pad={T_pad} (got "
-            f"t_block={t_block}, table={'none' if z_table is None else 'hbm'})")
+            f"t_block={t_block}, z_table={z_table is not None})")
     T_blk = t_block if blocked else T_pad
     lanes = _widest_lanes(P_pad, lanes_cap, T_blk, lanes_env)
     n_blocks = P_pad // lanes
@@ -3504,7 +3504,8 @@ def fused_vwap_sweep(close, volume, window, k, *, t_real=None,
     m = _fused_vwap_call(close, volume, onehot_w, k_lanes, warm,
                          _t_real_col(t_real, close),
                          windows=windows,
-                         T_pad=_band_t_pad(T, t_block), W_pad=onehot_w.shape[0],
+                         T_pad=_band_t_pad(T, t_block),
+                         W_pad=onehot_w.shape[0],
                          P_real=P, T_real=T if t_real is None else None,
                          cost=float(cost), ppy=int(periods_per_year),
                          interpret=bool(interpret),

@@ -74,9 +74,10 @@ class PanelCache:
     level by ``DBX_PANEL_CACHE_MB`` (default 256), the host level by the
     same knob where it is set, else by what the page pool holds
     (:func:`host_cache_max_bytes`); the page pool by ``DBX_PAGE_POOL_MB``
-    or a share of the device's memory (``page_pool.pool_max_bytes``). Eviction is not an error: the worker recovers
-    a digest-only miss through the dispatcher's ``FetchPayload`` RPC, and
-    a pool-rejected group falls back to the dense stack path.
+    or a share of the device's memory (``page_pool.pool_max_bytes``).
+    Eviction is not an error: the worker recovers a digest-only miss
+    through the dispatcher's ``FetchPayload`` RPC, and a pool-rejected
+    group falls back to the dense stack path.
     Thread-safe — the worker's control thread probes/fills the host level
     while the compute thread serves from all levels.
     """
