@@ -25,11 +25,21 @@ out over the grid's axes in the order of their names.
 Computed in blocks, so a year of minute bars fits: the band machine is
 one loop over the bars, vectorised over every (window, k) lane, keeping
 the positions as int8; the PnL and the summary run a block of windows at
-a time. Each row of the summary is reduced on its own, so the blocks
-give exactly the numbers of one unblocked pass (``block_windows=None``).
+a time, the blocks on threads (numpy releases the interpreter lock in
+them). Each row of the summary is reduced on its own, so the blocks give
+exactly the numbers of one unblocked pass (``block_windows=None``).
+
+The cell's precondition on the program under test is checked when this
+module is imported (:func:`require_fused_route`): a program whose router
+would send a year of minute bars to its generic (unfused) path stops the
+run at once instead of stalling in set-up.
 """
 
 from __future__ import annotations
+
+import concurrent.futures
+import os
+import types
 
 import numpy as np
 
@@ -40,8 +50,14 @@ AXES = ("window", "k")
 Z_EXIT = 0.0
 EPS = 1e-12
 # Windows per block of the PnL and summary (32 k-lanes each at the
-# cell's grid: 8 x 32 x 98,280 float64 bars is 200 MB).
+# cell's grid: 8 x 32 x 98,280 float64 bars is 200 MB, and ~1 GB live on
+# each thread).
 BLOCK_WINDOWS = 8
+THREADS = min(5, os.cpu_count() or 1)
+# A year of one-minute regular-session bars (390 x 252), and the
+# longest VWAP window of the cell's grid: what the program has to take.
+MINUTE_YEAR_BARS = 98280
+LONGEST_WINDOW = 390
 
 
 def _window_sum(cs: np.ndarray, w: int, dt) -> np.ndarray:
@@ -118,14 +134,17 @@ def sweep(legs, axes, *, cost: float, periods_per_year: int,
     pos_all = _band_positions(z, valid, k, dt)             # (T, W * K)
     r = simple_returns(close)
     step = len(wins) if block_windows is None else int(block_windows)
-    out = []
-    for w0 in range(0, len(wins), step):
+
+    def block(w0):
         lanes = slice(w0 * k.size, min(w0 + step, len(wins)) * k.size)
         pos = np.ascontiguousarray(pos_all[:, lanes].T).astype(dt)
         prev = lagged(pos)
         net = prev * r[None, :] - dt(cost) * np.abs(pos - prev)
-        out.append(summary(net, pos, periods_per_year=periods_per_year,
-                           dt=dt))
+        return summary(net, pos, periods_per_year=periods_per_year, dt=dt)
+
+    with concurrent.futures.ThreadPoolExecutor(
+            min(THREADS, -(-len(wins) // step))) as pool:
+        out = list(pool.map(block, range(0, len(wins), step)))
     out = np.concatenate(out, axis=1).reshape(9, len(wins), k.size)
     return np.ascontiguousarray(out.transpose(0, 2, 1)).reshape(9, -1)
 
@@ -164,3 +183,30 @@ def work(n_jobs: int, bars: int, axes) -> tuple[float, float]:
                            + combos * sum(PER_CELL_BAR.values()))
     nbytes = n_jobs * (bars * 4 * len(FIELDS) + combos * 9 * 4)
     return float(ops), float(nbytes)
+
+
+def require_fused_route() -> None:
+    """Stop the run (exit status 1) when the program under test would run
+    a year of minute bars on its generic path: its router's verdict on a
+    VWAP job of ``MINUTE_YEAR_BARS`` bars and windows up to
+    ``LONGEST_WINDOW``. The generic path holds every (combo, bar) array of
+    a job group in device memory and did not finish compiling one group
+    of this cell in minutes on the v5e, so the cell cannot run there. A
+    program without that router query is not judged here."""
+    try:
+        from distributed_backtesting_exploration_tpu.rpc.compute import (
+            JaxSweepBackend)
+        eligible = JaxSweepBackend._fused_eligible
+    except (ImportError, AttributeError):
+        return
+    job = types.SimpleNamespace(strategy="vwap_reversion")
+    grid = {"window": np.float32([10.0, LONGEST_WINDOW]),
+            "k": np.float32([1.0])}
+    if not eligible(job, grid, [MINUTE_YEAR_BARS]):
+        raise SystemExit(
+            "benchmark: the program routes a year of minute bars "
+            f"({MINUTE_YEAR_BARS} bars) of vwap_reversion to its generic "
+            "path, which cannot sweep this cell in a run; stopping")
+
+
+require_fused_route()

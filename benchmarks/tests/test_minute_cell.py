@@ -226,3 +226,13 @@ def test_tiny_run_with_altered_answers_is_not_correct(monkeypatch):
     monkeypatch.setattr(runner, "DRAIN_WAIT_S", 3.0)
     line = _tiny_run(_Altered)
     assert not line["correct"], line["checks"]
+
+
+def test_a_program_that_demotes_the_cell_stops_at_once(monkeypatch):
+    from distributed_backtesting_exploration_tpu.rpc import compute
+
+    _ref().require_fused_route()      # this program takes the cell
+    monkeypatch.setattr(compute.JaxSweepBackend, "_fused_eligible",
+                        classmethod(lambda cls, job, grid, lengths: False))
+    with pytest.raises(SystemExit, match="generic path"):
+        _ref().require_fused_route()
