@@ -50,9 +50,10 @@ AXES = ("window", "k")
 Z_EXIT = 0.0
 EPS = 1e-12
 # Windows per block of the PnL and summary (32 k-lanes each at the
-# cell's grid: 8 x 32 x 98,280 float64 bars is 200 MB, and ~1 GB live on
-# each thread).
-BLOCK_WINDOWS = 8
+# cell's grid: 2 x 32 x 98,280 float64 bars is 50 MB, ~0.5 GB live on
+# each thread; 8-window blocks took the process to 9 GB at the same
+# speed).
+BLOCK_WINDOWS = 2
 THREADS = min(5, os.cpu_count() or 1)
 # A year of one-minute regular-session bars (390 x 252), and the
 # longest VWAP window of the cell's grid: what the program has to take.
