@@ -29,20 +29,20 @@ a time, the blocks on threads (numpy releases the interpreter lock in
 them). Each row of the summary is reduced on its own, so the blocks give
 exactly the numbers of one unblocked pass (``block_windows=None``).
 
-The cell's precondition on the program under test is checked when this
-module is imported (:func:`require_fused_route`): a program whose router
-would send a year of minute bars to its generic (unfused) path stops the
-run at once instead of stalling in set-up.
+Importing this module checks the cell's one precondition on the program
+under test (:func:`harness.preflight.require_uncapped`): a program whose
+router would send a year of minute bars to its generic (unfused) path
+stops the run at once instead of stalling in set-up.
 """
 
 from __future__ import annotations
 
 import concurrent.futures
 import os
-import types
 
 import numpy as np
 
+from harness import preflight
 from reference.summary import lagged, simple_returns, summary
 
 FIELDS = ("close", "volume")
@@ -55,10 +55,9 @@ EPS = 1e-12
 # speed).
 BLOCK_WINDOWS = 2
 THREADS = min(5, os.cpu_count() or 1)
-# A year of one-minute regular-session bars (390 x 252), and the
-# longest VWAP window of the cell's grid: what the program has to take.
+# A year of one-minute regular-session bars (390 x 252): the history the
+# program has to take on its fused route.
 MINUTE_YEAR_BARS = 98280
-LONGEST_WINDOW = 390
 
 
 def _window_sum(cs: np.ndarray, w: int, dt) -> np.ndarray:
@@ -186,28 +185,4 @@ def work(n_jobs: int, bars: int, axes) -> tuple[float, float]:
     return float(ops), float(nbytes)
 
 
-def require_fused_route() -> None:
-    """Stop the run (exit status 1) when the program under test would run
-    a year of minute bars on its generic path: its router's verdict on a
-    VWAP job of ``MINUTE_YEAR_BARS`` bars and windows up to
-    ``LONGEST_WINDOW``. The generic path holds every (combo, bar) array of
-    a job group in device memory and did not finish compiling one group
-    of this cell in minutes on the v5e, so the cell cannot run there. A
-    program without that router query is not judged here."""
-    try:
-        from distributed_backtesting_exploration_tpu.rpc.compute import (
-            JaxSweepBackend)
-        eligible = JaxSweepBackend._fused_eligible
-    except (ImportError, AttributeError):
-        return
-    job = types.SimpleNamespace(strategy="vwap_reversion")
-    grid = {"window": np.float32([10.0, LONGEST_WINDOW]),
-            "k": np.float32([1.0])}
-    if not eligible(job, grid, [MINUTE_YEAR_BARS]):
-        raise SystemExit(
-            "benchmark: the program routes a year of minute bars "
-            f"({MINUTE_YEAR_BARS} bars) of vwap_reversion to its generic "
-            "path, which cannot sweep this cell in a run; stopping")
-
-
-require_fused_route()
+preflight.require_uncapped("vwap_reversion", MINUTE_YEAR_BARS)

@@ -1,7 +1,8 @@
 """The minute-bar VWAP cell (``sp500_minute_vwap.sweep``): its plain
 reference against the system's generic path, the reference's blocked and
-unblocked forms, its readers on synthetic runs, and a whole tiny run on
-the CPU that reads correct, and not correct with a planted fault."""
+unblocked forms, its readers on synthetic runs, a whole tiny run on the
+CPU that reads correct, and not correct with either planted fault, and
+the preflight that stops a program which cannot run the cell."""
 
 import copy
 import importlib
@@ -170,9 +171,8 @@ def test_the_cell_is_declared_with_its_metrics():
     assert config["correct"]["sample_jobs"]["closed"] == 8
 
 
-class _Altered:
-    """The backend with every answer altered where it is produced: the
-    Sortino row of each result block scaled by 1.05."""
+class _Wrapped:
+    """The backend, with attribute reads and writes passed through."""
 
     def __init__(self, inner):
         object.__setattr__(self, "inner", inner)
@@ -185,6 +185,25 @@ class _Altered:
 
     def submit(self, jobs):
         return self.inner.submit(jobs)
+
+    def collect(self, pending):
+        return self.inner.collect(pending)
+
+
+class _HalfBatch(_Wrapped):
+    """Leaves out the second half of every batch of the timed phase (the
+    warm-up's jobs, ``warm...``, pass untouched)."""
+
+    def submit(self, jobs):
+        jobs = list(jobs)
+        if any(not j.id.startswith("warm") for j in jobs):
+            jobs = jobs[:(len(jobs) + 1) // 2]
+        return self.inner.submit(jobs)
+
+
+class _Altered(_Wrapped):
+    """Alters every answer where it is produced: the Sortino row of each
+    result block scaled by 1.05."""
 
     def collect(self, pending):
         from distributed_backtesting_exploration_tpu.rpc import wire
@@ -222,17 +241,36 @@ def test_tiny_run_is_correct():
     assert set(line["metrics"]) >= {"setup_s", "backtests_per_s"}
 
 
-def test_tiny_run_with_altered_answers_is_not_correct(monkeypatch):
+@pytest.mark.parametrize("fault", [_HalfBatch, _Altered],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_tiny_run_with_a_planted_fault_is_not_correct(fault, monkeypatch):
     monkeypatch.setattr(runner, "DRAIN_WAIT_S", 3.0)
-    line = _tiny_run(_Altered)
+    line = _tiny_run(fault)
     assert not line["correct"], line["checks"]
+    if fault is _HalfBatch:
+        assert line["checks"]["missing"]["value"] > 0, line["checks"]
 
 
-def test_a_program_that_demotes_the_cell_stops_at_once(monkeypatch):
+def test_preflight_passes_this_program():
+    from harness import preflight
+
+    preflight.require_uncapped("vwap_reversion", 98280)
+
+
+def test_preflight_stops_a_program_that_demotes_the_cell(monkeypatch):
     from distributed_backtesting_exploration_tpu.rpc import compute
+    from harness import preflight
 
-    _ref().require_fused_route()      # this program takes the cell
-    monkeypatch.setattr(compute.JaxSweepBackend, "_fused_eligible",
-                        classmethod(lambda cls, job, grid, lengths: False))
+    monkeypatch.setattr(compute, "bar_capped",
+                        lambda strategy, bars, cap=None: True)
     with pytest.raises(SystemExit, match="generic path"):
-        _ref().require_fused_route()
+        preflight.require_uncapped("vwap_reversion", 98280)
+
+
+def test_preflight_stops_a_program_without_the_cap_test(monkeypatch):
+    from distributed_backtesting_exploration_tpu.rpc import compute
+    from harness import preflight
+
+    monkeypatch.delattr(compute, "bar_capped")
+    with pytest.raises(SystemExit, match="no router cap test"):
+        preflight.require_uncapped("vwap_reversion", 98280)

@@ -2765,13 +2765,15 @@ class JaxSweepBackend:
         return self.collect(self.submit(jobs))
 
 
-def bar_capped(strategy: str, bars: int, cap: int) -> bool:
+def bar_capped(strategy: str, bars: int, cap: int | None = None) -> bool:
     """The router's one bar-cap test: True when a ``bars``-bar history is
     longer than the fused kernel of ``strategy`` takes on one chip —
-    ``cap`` bars (``JaxSweepBackend._FUSED_MAX_BARS``, the longest
-    history every kernel holds whole in VMEM), unless the kernel walks
-    the history in T blocks (``ops.fused.T_BLOCKED_FAMILIES``) and takes
-    any length."""
+    ``cap`` bars (by default ``JaxSweepBackend._FUSED_MAX_BARS``, the
+    longest history every kernel holds whole in VMEM), unless the kernel
+    walks the history in T blocks (``ops.fused.T_BLOCKED_FAMILIES``) and
+    takes any length."""
+    if cap is None:
+        cap = JaxSweepBackend._FUSED_MAX_BARS
     if bars <= cap:
         return False
     from ..ops import fused

@@ -33,11 +33,53 @@ def _bollinger(panel, grid, lens, **kw):
         t_real=lens, cost=1e-3, **kw)
 
 
+def _touch(panel, grid, lens, **kw):
+    return fused.fused_bollinger_touch_sweep(
+        panel.close, np.asarray(grid["window"]), np.asarray(grid["k"]),
+        t_real=lens, cost=1e-3, **kw)
+
+
+def _rsi(panel, grid, lens, **kw):
+    return fused.fused_rsi_sweep(
+        panel.close, np.asarray(grid["period"]), np.asarray(grid["band"]),
+        t_real=lens, cost=1e-3, **kw)
+
+
+def _stochastic(panel, grid, lens, **kw):
+    return fused.fused_stochastic_sweep(
+        panel.close, panel.high, panel.low, np.asarray(grid["window"]),
+        np.asarray(grid["band"]), t_real=lens, cost=1e-3, **kw)
+
+
+def _keltner(panel, grid, lens, **kw):
+    return fused.fused_keltner_sweep(
+        panel.close, panel.high, panel.low, np.asarray(grid["window"]),
+        np.asarray(grid["k"]), t_real=lens, cost=1e-3, **kw)
+
+
+# Every family in ops.fused.T_BLOCKED_FAMILIES: its wrapper, a small grid,
+# and the tolerance its fused-vs-generic test in test_fused.py holds
+# (Keltner's in-prep EMA ladder rounds apart from the generic scan).
+TIGHT = dict(rtol=2e-4, atol=2e-5)
 FAMILIES = {
     "vwap_reversion": (_vwap, dict(window=[10.0, 20.0, 40.0],
-                                   k=[0.5, 1.0, 2.0])),
-    "bollinger": (_bollinger, dict(window=[10.0, 25.0], k=[0.5, 1.5])),
+                                   k=[0.5, 1.0, 2.0]), TIGHT),
+    "bollinger": (_bollinger, dict(window=[10.0, 25.0], k=[0.5, 1.5]),
+                  TIGHT),
+    "bollinger_touch": (_touch, dict(window=[10.0, 20.0], k=[1.0, 2.0]),
+                        TIGHT),
+    "rsi": (_rsi, dict(period=[7.0, 14.0], band=[15.0, 25.0]), TIGHT),
+    "stochastic": (_stochastic, dict(window=[10.0, 21.0],
+                                     band=[20.0, 30.0]), TIGHT),
+    "keltner": (_keltner, dict(window=[10.0, 21.0], k=[1.5, 2.5]),
+                dict(rtol=2e-3, atol=2e-4)),
 }
+
+
+def test_every_t_blocked_family_is_tested_here():
+    """The router takes any history length for exactly these families
+    (`rpc.compute.bar_capped`), so each has its cases below."""
+    assert set(FAMILIES) == fused.T_BLOCKED_FAMILIES
 
 
 def _grid(axes):
@@ -45,18 +87,18 @@ def _grid(axes):
         **{k: jnp.asarray(v, jnp.float32) for k, v in axes.items()})
 
 
-def _assert_close(got, ref):
+def _assert_close(got, ref, tol=TIGHT):
     for name in ref._fields:
         np.testing.assert_allclose(
             np.asarray(getattr(got, name)), np.asarray(getattr(ref, name)),
-            rtol=2e-4, atol=2e-5, err_msg=name)
+            err_msg=name, **tol)
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 @pytest.mark.parametrize("T", [3 * BLOCK + 50, BLOCK - 28],
                          ids=["3-blocks-and-a-ragged-tail", "one-block"])
 def test_t_blocked_cell_matches_generic(family, T):
-    call, axes = FAMILIES[family]
+    call, axes, tol = FAMILIES[family]
     ohlcv = data.synthetic_ohlcv(3, T, seed=T)
     panel = type(ohlcv)(*(jnp.asarray(f) for f in ohlcv))
     grid = _grid(axes)
@@ -64,7 +106,7 @@ def test_t_blocked_cell_matches_generic(family, T):
                           cost=1e-3)
     got = call(panel, grid, None, t_block=BLOCK)
     assert float(np.asarray(ref.turnover).max()) > 0   # some cells trade
-    _assert_close(got, ref)
+    _assert_close(got, ref, tol)
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -72,7 +114,7 @@ def test_t_blocked_cell_ragged_rows(family):
     """Rows of 434, 300 and 129 real bars in one 4-block launch: each
     row's last real bar lies in another block, and its held position
     rides the carry through the blocks after it."""
-    call, axes = FAMILIES[family]
+    call, axes, tol = FAMILIES[family]
     series = []
     for i, T in enumerate([434, 300, 129]):
         one = data.synthetic_ohlcv(1, T, seed=80 + i)
@@ -82,18 +124,18 @@ def test_t_blocked_cell_ragged_rows(family):
     grid = _grid(axes)
     ref = sweep.jit_sweep(panel, get_strategy(family), dict(grid),
                           cost=1e-3, bar_mask=jnp.asarray(mask))
-    _assert_close(call(panel, grid, lens, t_block=BLOCK), ref)
+    _assert_close(call(panel, grid, lens, t_block=BLOCK), ref, tol)
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_single_t_block_is_bit_identical_to_the_unblocked_cell(family):
     """The carry machinery over one block computes the program the
     one-block cell runs (the daily cells' program), to the bit."""
-    call, axes = FAMILIES[family]
+    call, axes, _ = FAMILIES[family]
     ohlcv = data.synthetic_ohlcv(2, 251, seed=5)
     panel = type(ohlcv)(*(jnp.asarray(f) for f in ohlcv))
     grid = _grid(axes)
-    kw = {"table": "hbm"} if family == "bollinger" else {}
+    kw = {"table": "hbm"} if family.startswith("bollinger") else {}
     whole = call(panel, grid, None, **kw)
     blocked = call(panel, grid, None, t_block=256, **kw)
     for name in whole._fields:

@@ -118,37 +118,76 @@ def test_router_bar_cap(one_chip):
 
 
 # A year of minute bars (390 x 252) on the sp500_minute_vwap cell's grid:
-# 39 VWAP windows x 32 bands, through the T-blocked band machine.
+# 39 windows x 32 bands, through the T-blocked band machine. The other
+# band families take the same shape, on their own axis names.
 MINUTE_BARS = 98280
-MINUTE_AXES = {"window": np.arange(10, 400, 10, dtype=np.float32),
+MINUTE_WINDOWS = np.arange(10, 400, 10, dtype=np.float32)
+MINUTE_AXES = {"window": MINUTE_WINDOWS,
                "k": np.arange(0.5, 4.5, 0.125, dtype=np.float32)}
+MINUTE_BANDS = np.arange(5.0, 37.0, dtype=np.float32)
+
+# strategy -> (window axis, band axis, OHLCV fields the kernel reads,
+# the fused call over those fields and the grid ``g``).
+BAND_FAMILIES = {
+    "pairs": ("lookback", "z_entry", 2, lambda g, y, x:
+              fused.fused_pairs_sweep(y, x, g["lookback"], g["z_entry"],
+                                      cost=1e-3, interpret=False)),
+    "vwap_reversion": ("window", "k", 2, lambda g, c, v:
+                       fused.fused_vwap_sweep(c, v, g["window"], g["k"],
+                                              cost=1e-3, interpret=False)),
+    "bollinger": ("window", "k", 1, lambda g, c:
+                  fused.fused_bollinger_sweep(c, g["window"], g["k"],
+                                              cost=1e-3, interpret=False)),
+    "bollinger_touch": ("window", "k", 1, lambda g, c:
+                        fused.fused_bollinger_touch_sweep(
+                            c, g["window"], g["k"], cost=1e-3,
+                            interpret=False)),
+    "rsi": ("period", "band", 1, lambda g, c:
+            fused.fused_rsi_sweep(c, g["period"], g["band"], cost=1e-3,
+                                  interpret=False)),
+    "stochastic": ("window", "band", 3, lambda g, c, h, lo:
+                   fused.fused_stochastic_sweep(c, h, lo, g["window"],
+                                                g["band"], cost=1e-3,
+                                                interpret=False)),
+    "keltner": ("window", "k", 3, lambda g, c, h, lo:
+                fused.fused_keltner_sweep(c, h, lo, g["window"], g["k"],
+                                          cost=1e-3, interpret=False)),
+}
 
 
 @pytest.mark.parametrize("strategy,bars", [
     pytest.param("pairs", None, id="pairs"),
     pytest.param("vwap_reversion", None, id="vwap_reversion"),
     pytest.param("vwap_reversion", MINUTE_BARS, id="vwap_reversion-minute"),
+    pytest.param("bollinger", MINUTE_BARS, id="bollinger-minute"),
+    pytest.param("bollinger_touch", MINUTE_BARS,
+                 id="bollinger_touch-minute"),
+    pytest.param("rsi", MINUTE_BARS, id="rsi-minute"),
+    pytest.param("stochastic", MINUTE_BARS, id="stochastic-minute"),
+    pytest.param("keltner", MINUTE_BARS, id="keltner-minute"),
 ])
 def test_router_group_at_the_bar_cap_fits_hbm(one_chip, strategy, bars):
     """The largest group the router forms at its bar cap, for the two
     families that take the most device memory there (their 3-D z-table
     preps: pairs ~2.8 KB per ticker and bar at 128 windows, VWAP ~2.3
     KB): the compiled program fits the HBM budget the router sizes
-    groups by. The minute case is a year of minute bars on the
-    sp500_minute_vwap grid, past the cap: the T-blocked ``vwap_kernel``
-    compiles there, and the router's group fits."""
+    groups by. The minute cases are a year of minute bars, past the cap,
+    on the sp500_minute_vwap grid's shape (39 windows x 32 bands), for
+    every family the router takes past it (``ops.fused.
+    T_BLOCKED_FAMILIES``): the T-blocked kernel compiles there, and the
+    router's group fits."""
     from distributed_backtesting_exploration_tpu.rpc import (
         backtesting_pb2 as pb, wire)
 
-    window = "lookback" if strategy == "pairs" else "window"
-    k = "z_entry" if strategy == "pairs" else "k"
+    window, k, fields, call = BAND_FAMILIES[strategy]
     if bars is None:
         bars = JaxSweepBackend._FUSED_MAX_BARS
         axes = {window: np.arange(JaxSweepBackend._FUSED_MAX_WINDOWS,
                                   dtype=np.float32) * 2 + 10,
                 k: np.linspace(0.5, 3.0, 8).astype(np.float32)}
     else:
-        axes = MINUTE_AXES
+        axes = {window: MINUTE_WINDOWS,
+                k: MINUTE_AXES["k"] if k == "k" else MINUTE_BANDS}
     legs = 2 if strategy == "pairs" else 0
     job = pb.JobSpec(strategy=strategy, panel_bytes_len=8 + 20 * bars,
                      panel_bytes_len2=legs and 8 + 20 * bars,
@@ -156,18 +195,14 @@ def test_router_group_at_the_bar_cap_fits_hbm(one_chip, strategy, bars):
     n = JaxSweepBackend(use_fused=False, use_mesh=False)._group_limit([job])
     g = _np(sweep.product_grid(**axes))
     args = [jax.ShapeDtypeStruct((n, bars), jnp.float32, sharding=one_chip)
-            for _ in range(2)]
-    if strategy == "pairs":
-        def run(y, x):
-            return fused.fused_pairs_sweep(y, x, g[window], g[k], cost=1e-3,
-                                           interpret=False)
-    else:
-        def run(close, volume):
-            return fused.fused_vwap_sweep(close, volume, g[window], g[k],
-                                          cost=1e-3, interpret=False)
-    compiled = jax.jit(run).lower(*args).compile()
+            for _ in range(fields)]
+    compiled = jax.jit(lambda *a: call(g, *a)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     ma = compiled.memory_analysis()
     used = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
             + ma.output_size_in_bytes)
     assert used <= JaxSweepBackend._HBM_BUDGET, (n, used)
+
+
+def test_minute_families_are_the_t_blocked_ones():
+    assert set(BAND_FAMILIES) - {"pairs"} == fused.T_BLOCKED_FAMILIES
